@@ -10,12 +10,12 @@ reduced-depth compiles (dryrun --depth d1/d2):
 (exact for per-layer-homogeneous stacks; zamba2 uses d∈{6,12} so each
 segment holds one shared-attention application).
 
-Terms (TPU v5e, per chip — cost_analysis of a partitioned module is already
-the per-device program):
-    compute    = FLOPs / 197e12            (bf16; fp32 ops counted at bf16
-                                            peak — conservative)
-    memory     = bytes / 819e9
-    collective = collective_bytes / 50e9   (per-device bytes over ICI)
+Terms (TPU v5e peaks from `CHIP_SPECS`, per chip — cost_analysis of a
+partitioned module is already the per-device program):
+    compute    = FLOPs / peak bf16 FLOP/s   (fp32 ops counted at bf16
+                                             peak — conservative)
+    memory     = bytes / HBM B/s
+    collective = collective_bytes / ICI B/s (per-device bytes over ICI)
 
     MODEL_FLOPS = 6·N_active·tokens (train) | 2·N_active·tokens (serve)
 """
@@ -30,11 +30,13 @@ sys.path.insert(0, str(ROOT / "src"))
 
 from repro.configs import get_arch, get_shape, list_archs  # noqa: E402
 from repro.configs.shapes import SHAPES  # noqa: E402
+from repro.core.cost_model import CHIP_SPECS  # noqa: E402
 
 DRY = ROOT / "results" / "dryrun"
-PEAK = 197e12
-HBM = 819e9
-ICI = 50e9
+V5E = CHIP_SPECS["TPU v5 lite"]  # the dry-run meshes are v5e pods
+PEAK = V5E.peak_flops_bf16
+HBM = V5E.hbm_bw
+ICI = V5E.ici_bw
 CHIPS = 256
 
 

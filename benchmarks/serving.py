@@ -78,6 +78,7 @@ from repro.core import (  # noqa: E402
 from repro.core.gemm_desc import GemmDesc  # noqa: E402
 from repro.core.scheduler import GemmRequest  # noqa: E402
 from repro.core.op_desc import slice_plan  # noqa: E402
+from repro.kernels.dispatch import interpret_mode  # noqa: E402
 from repro.runtime import (  # noqa: E402
     FaultInjector,
     FaultRule,
@@ -562,7 +563,8 @@ def run_chaos(
             ], seed=seed)
         rt = Runtime(
             ConcurrencyController(library=GOLibrary()),
-            RuntimeConfig(window_s=1e-3, execute=True, interpret=True),
+            RuntimeConfig(window_s=1e-3, execute=True,
+                          interpret=interpret_mode()),
             fault_injector=inj)
         rt.prewarm(descs)
         tickets = []
@@ -654,7 +656,7 @@ def chaos_main(argv=None) -> int:
 
 def verify_execute() -> None:
     """End-to-end kernel check: one reduced-config decode flush through the
-    real pallas kernels (interpret mode) vs the XLA reference."""
+    real pallas kernels (interpret mode off-TPU) vs the XLA reference."""
     import jax
     import jax.numpy as jnp
 
@@ -662,7 +664,7 @@ def verify_execute() -> None:
     lib = GOLibrary()
     ctrl = ConcurrencyController(library=lib)
     rt = Runtime(ctrl, RuntimeConfig(window_s=0.0, execute=True,
-                                     interpret=True))
+                                     interpret=interpret_mode()))
     key = jax.random.PRNGKey(0)
     tickets = []
     # Three concurrent decode streams so the planner emits grouped launches.
@@ -682,8 +684,9 @@ def verify_execute() -> None:
         ref = tk.request.a @ tk.request.b
         np.testing.assert_allclose(tk.result, ref, rtol=3e-4, atol=3e-4)
     modes = rt.telemetry.mode_counts()
+    how = "interpret" if interpret_mode() else "compiled"
     print(f"# verify: {len(tickets)} GEMMs executed through pallas "
-          f"(interpret) and matched reference; modes={modes}")
+          f"({how}) and matched reference; modes={modes}")
 
 
 def main(argv=None) -> Dict[str, Dict[str, Dict[str, float]]]:

@@ -15,6 +15,7 @@ from repro.core import (
     profile_dataset,
     train_predictor,
 )
+from repro.kernels.dispatch import interpret_mode
 
 
 def main():
@@ -48,7 +49,7 @@ def main():
     for g in sched.groups:
         print(f"  plan: {g.mode} CD={g.cd} tile={g.tile.key()} "
               f"modeled {g.modeled_time_s * 1e6:.1f} us")
-    outs = ctrl.execute(reqs, interpret=True)  # real pallas kernels
+    outs = ctrl.execute(reqs, interpret=interpret_mode())  # real pallas kernels
     ref = reqs[0].a @ reqs[0].b
     np.testing.assert_allclose(outs[0], ref, rtol=2e-4, atol=2e-4)
     print("  executed through grouped pallas kernel: results verified ✓")

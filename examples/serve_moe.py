@@ -51,7 +51,7 @@ def main(argv=None):
         model, params, prompt,
         s_max=args.prompt_len + args.gen + 1, steps=args.gen,
         runtime=runtime, tenant=cfg.name, mixed_ops=args.mixed_ops,
-    )
+    ).tokens
     dt = time.time() - t0
     print(f"[serve_moe] batch={args.batch} prompt={args.prompt_len} "
           f"gen={args.gen}: {args.batch * args.gen / dt:.1f} tok/s")

@@ -1,11 +1,13 @@
 """GOLDYLOC core: globally-optimized GEMM kernels + lightweight dynamic
 concurrency control, adapted to TPU (see DESIGN.md)."""
 from repro.core.cost_model import (
+    CHIP_SPECS,
     DEFAULT_SPEC,
     RC_FRACTIONS,
     SLICE_OVERHEAD_S,
     CostCalibrator,
     TPUSpec,
+    device_spec,
     group_time,
     isolated_time,
     kernel_stats,
@@ -59,7 +61,8 @@ from repro.core.tuner import (
 )
 
 __all__ = [
-    "DEFAULT_SPEC", "RC_FRACTIONS", "TPUSpec", "group_time", "isolated_time",
+    "CHIP_SPECS", "DEFAULT_SPEC", "RC_FRACTIONS", "TPUSpec", "device_spec",
+    "group_time", "isolated_time",
     "kernel_stats", "sequential_time", "speedup_vs_sequential", "GemmDesc",
     "CostCalibrator", "Measurement", "Measurer", "backend_tag",
     "execute_schedule", "SLICE_OVERHEAD_S", "sliced_time", "split_spans",

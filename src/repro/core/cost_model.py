@@ -56,25 +56,28 @@ import math
 from dataclasses import dataclass, replace
 from typing import Sequence
 
+import jax
 import numpy as np
 
 from repro.core.gemm_desc import GemmDesc
 from repro.core.op_desc import AttentionDesc, GroupedGemmDesc, ScanDesc, family_of
+from repro.kernels.dispatch import VMEM_LIMIT_BYTES
 from repro.kernels.gemm.ops import TileConfig
 
 
 @dataclass(frozen=True)
 class TPUSpec:
-    """TPU v5e-class chip (targets in the assignment)."""
+    """One chip's peaks (from `CHIP_SPECS`) plus the model's planning
+    knobs."""
 
-    name: str = "tpu-v5e"
-    peak_flops_bf16: float = 197e12
-    peak_flops_fp32: float = 98.5e12
-    hbm_bw: float = 819e9            # B/s
-    vmem_bytes: int = 32 * 2**20     # usable per-core VMEM (v5e-class)
+    name: str
+    peak_flops_bf16: float
+    peak_flops_fp32: float
+    hbm_bw: float                    # B/s
+    ici_bw: float                    # B/s of chip-to-chip interconnect
+    vmem_bytes: int = VMEM_LIMIT_BYTES  # VMEM budget tiles are planned in
     launch_overhead_s: float = 3e-6  # kernel dispatch
     pipeline_fill_tiles: int = 2     # DMA double-buffer fill/drain depth
-    ici_bw: float = 50e9             # per-link, used by dist roofline
     mxu_dim: int = 128
 
     def peak(self, dtype: str) -> float:
@@ -90,7 +93,43 @@ class TPUSpec:
         )
 
 
-DEFAULT_SPEC = TPUSpec()
+# Published per-chip peaks, keyed by `jax.Device.device_kind`.  Source:
+# Google Cloud TPU documentation, "TPU v5e" — 197 TFLOP/s bf16, 16 GB HBM
+# at 819 GB/s, 1,600 Gbit/s chip-to-chip interconnect.  The f32 peak is
+# the model's assumption (half the bf16 MXU rate), not a published figure.
+CHIP_SPECS = {
+    "TPU v5 lite": TPUSpec(
+        name="tpu-v5e",
+        peak_flops_bf16=197e12,
+        peak_flops_fp32=98.5e12,
+        hbm_bw=819e9,
+        ici_bw=1600e9 / 8,
+    ),
+}
+
+# The planning target off-TPU (CPU runs and tests plan for a v5e).
+DEFAULT_SPEC = CHIP_SPECS["TPU v5 lite"]
+
+
+def device_spec(device=None) -> TPUSpec:
+    """The spec of ``device`` (default: the process's first device).
+
+    A TPU plans with its own published peaks; a TPU whose kind is not in
+    `CHIP_SPECS` is an error, never a v5e stand-in.  Other platforms plan
+    for `DEFAULT_SPEC`."""
+    if device is None:
+        device = jax.devices()[0]
+    if device.platform != "tpu":
+        return DEFAULT_SPEC
+    try:
+        return CHIP_SPECS[device.device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no TPUSpec for device kind {device.device_kind!r}; add its "
+            f"published peaks to CHIP_SPECS (known: {sorted(CHIP_SPECS)})"
+        ) from None
+
+
 RC_FRACTIONS = {"GPU": 1.0, "GPU/2": 0.5, "GPU/4": 0.25}
 
 _STRIDED_DMA = 1 / 0.85  # paper Fig. 5(b) ③: strided operand loses ~15%

@@ -43,7 +43,7 @@ from dataclasses import dataclass, field, replace as dc_replace
 from pathlib import Path
 from typing import Dict, FrozenSet, Optional, Sequence
 
-from repro.core.cost_model import DEFAULT_SPEC, TPUSpec
+from repro.core.cost_model import TPUSpec, device_spec
 from repro.core.gemm_desc import GemmDesc
 from repro.core.tuner import CDS, GOEntry, tune_gemm, tune_op
 from repro.kernels.gemm.ops import TileConfig
@@ -71,10 +71,10 @@ class GOLibrary:
     def __init__(
         self,
         path: str | os.PathLike | None = None,
-        spec: TPUSpec = DEFAULT_SPEC,
+        spec: TPUSpec | None = None,
     ):
         self.path = Path(path) if path else None
-        self.spec = spec
+        self.spec = device_spec() if spec is None else spec
         self._entries: Dict[str, GOEntry] = {}
         self._lock = threading.Lock()
         self.loaded_schema: Optional[int] = None

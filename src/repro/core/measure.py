@@ -42,7 +42,7 @@ from typing import Dict, List, Optional, Sequence
 import jax
 import jax.numpy as jnp
 
-from repro.core.cost_model import DEFAULT_SPEC, RC_FRACTIONS, TPUSpec
+from repro.core.cost_model import RC_FRACTIONS, TPUSpec, device_spec
 from repro.core.gemm_desc import GemmDesc
 from repro.core.op_desc import family_of
 from repro.core.scheduler import (
@@ -51,6 +51,7 @@ from repro.core.scheduler import (
     Schedule,
     execute_schedule,
 )
+from repro.kernels.dispatch import interpret_mode
 from repro.kernels.gemm.ops import TileConfig
 
 
@@ -176,29 +177,29 @@ def _run_key(desc_keys, tiles, cd, backend, warmup, repeats, seed) -> str:
 class Measurer:
     """The timing harness.  ``clock`` is injectable (tests script it to
     verify warmup exclusion and outlier rejection without real sleeps);
-    ``interpret=True`` is the first-class CPU backend, ``False`` times
-    hardware when a TPU is attached."""
+    ``interpret=None`` follows the backend (`interpret_mode`): interpret
+    on the CPU, compiled kernels timed on a TPU."""
 
     def __init__(
         self,
-        spec: TPUSpec = DEFAULT_SPEC,
+        spec: TPUSpec | None = None,
         *,
         warmup: int = 1,
         repeats: int = 5,
-        interpret: bool | None = True,
+        interpret: bool | None = None,
         clock=time.perf_counter,
         outlier_k: float = 4.0,
         seed: int = 0,
         deadline_s: float | None = None,
     ):
-        self.spec = spec
+        self.spec = device_spec() if spec is None else spec
         self.warmup = max(0, int(warmup))
         self.repeats = max(1, int(repeats))
-        self.interpret = interpret
+        self.interpret = interpret_mode() if interpret is None else interpret
         self.clock = clock
         self.outlier_k = float(outlier_k)
         self.seed = int(seed)
-        self.backend = backend_tag(interpret)
+        self.backend = backend_tag(self.interpret)
         # Watchdog (DESIGN.md §18.4): a timed sample whose clock bracket
         # exceeds the deadline is recorded as ``inf`` — MAD rejection
         # discards a minority of hangs, and an all-hung launch yields a

@@ -36,9 +36,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.cost_model import (
-    DEFAULT_SPEC,
     CostCalibrator,
     TPUSpec,
+    device_spec,
     group_time,
     isolated_time,
     sequential_time,
@@ -146,7 +146,7 @@ class ConcurrencyController:
         self,
         library: GOLibrary | None = None,
         predictor: Predictor | None = None,
-        spec: TPUSpec = DEFAULT_SPEC,
+        spec: TPUSpec | None = None,
         max_cd: int = 16,
         go_tiles: bool = True,
         calibrator: CostCalibrator | None = None,
@@ -155,7 +155,7 @@ class ConcurrencyController:
         # GOLibrary (its __len__ makes it falsy) — compare to None.
         self.lib = library if library is not None else default_library()
         self.predictor = predictor
-        self.spec = spec
+        self.spec = device_spec() if spec is None else spec
         self.max_cd = max_cd
         # go_tiles=False plans grouped launches with the isolated-tuned tile
         # (the paper's "default" baseline; used by benchmark baselines).

@@ -5,17 +5,14 @@ see DESIGN.md §9).  Tests force ``interpret=True`` explicitly.
 """
 from __future__ import annotations
 
-import os
 from contextlib import contextmanager
 
 import jax
-from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams; support both so the
-# kernels import on every toolchain the container may carry.
-tpu_compiler_params = getattr(
-    pltpu, "CompilerParams", getattr(pltpu, "TPUCompilerParams", None)
-)
+# Scoped VMEM every kernel may use: the budget the cost model plans tiles
+# against (`TPUSpec.vmem_bytes`).  Mosaic's default scoped limit on v5e is
+# 16 MiB, which refuses the deepest split-K reduce block in the tile space.
+VMEM_LIMIT_BYTES = 32 * 2**20
 
 _FORCED: bool | None = None
 
@@ -24,8 +21,6 @@ def use_pallas() -> bool:
     """True when pallas kernels should be used for the hot paths."""
     if _FORCED is not None:
         return _FORCED
-    if os.environ.get("REPRO_FORCE_PALLAS"):
-        return True
     return jax.default_backend() == "tpu"
 
 

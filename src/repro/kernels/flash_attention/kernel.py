@@ -15,7 +15,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.dispatch import tpu_compiler_params
+from repro.kernels.dispatch import VMEM_LIMIT_BYTES
 
 NEG_INF = -1e30
 
@@ -48,9 +48,12 @@ def _flash_kernel(
     k_lo = jk * bkv
 
     def _compute():
-        q = q_ref[0].astype(jnp.float32) * scale
-        k = k_ref[0].astype(jnp.float32)
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32)  # (bq, bkv)
+        # MXU operands stay in the storage dtype: bf16 products are exact
+        # in the f32 accumulator, so the scores do not depend on the
+        # backend's f32 matmul precision (and neither do `flash_ref`'s).
+        s = jax.lax.dot_general(
+            q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale       # (bq, bkv)
         qpos = q_lo + jax.lax.broadcasted_iota(jnp.int32, (bq, bkv), 0)
         kpos = k_lo + jax.lax.broadcasted_iota(jnp.int32, (bq, bkv), 1)
         msk = kpos < s_len
@@ -68,9 +71,9 @@ def _flash_kernel(
         l_ref[...] = l_ref[...] * alpha + jnp.broadcast_to(
             p.sum(-1, keepdims=True), l_ref.shape
         )
-        v = v_ref[0].astype(jnp.float32)
+        v = v_ref[0]
         acc_ref[...] = acc_ref[...] * alpha[:, :1] + jnp.dot(
-            p, v, preferred_element_type=jnp.float32
+            p.astype(v.dtype), v, preferred_element_type=jnp.float32
         )
         m_ref[...] = m_new
 
@@ -145,7 +148,8 @@ def flash_attention_pallas(
             pltpu.VMEM((bq, 128), jnp.float32),
             pltpu.VMEM((bq, D), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=VMEM_LIMIT_BYTES,
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -63,16 +63,17 @@ def flash_ref(q, k, v, *, causal=True, window=0, scale=None, q_offset=0,
     kb = k.reshape(B, Hkv, nkv, block_kv, D).transpose(2, 0, 1, 3, 4)
     vb = v.reshape(B, Hkv, nkv, block_kv, Dv).transpose(2, 0, 1, 3, 4)
     qpos = jnp.arange(T) + q_offset
-    qf = (q * scale).astype(jnp.float32)
 
+    # Same roundings as the kernel: scores from storage-dtype operands
+    # (exact products, f32 accumulation), probabilities cast to v's dtype.
     def step(carry, blk):
         m_prev, l_prev, acc = carry
         kblk, vblk, j = blk
         kpos = j * block_kv + jnp.arange(block_kv)
         krep = jnp.repeat(kblk, rep, axis=1)  # (B, Hq, bkv, D)
         s = jnp.einsum(
-            "bhtd,bhsd->bhts", qf, krep.astype(jnp.float32)
-        )
+            "bhtd,bhsd->bhts", q, krep, preferred_element_type=jnp.float32
+        ) * scale
         msk = jnp.ones((T, block_kv), bool)
         if causal:
             msk &= qpos[:, None] >= kpos[None, :]
@@ -84,8 +85,10 @@ def flash_ref(q, k, v, *, causal=True, window=0, scale=None, q_offset=0,
         p = jnp.exp(s - m_new[..., None])
         alpha = jnp.exp(m_prev - m_new)
         l_new = l_prev * alpha + p.sum(-1)
-        vrep = jnp.repeat(vblk, rep, axis=1).astype(jnp.float32)
-        acc = acc * alpha[..., None] + jnp.einsum("bhts,bhsd->bhtd", p, vrep)
+        vrep = jnp.repeat(vblk, rep, axis=1)
+        acc = acc * alpha[..., None] + jnp.einsum(
+            "bhts,bhsd->bhtd", p.astype(vrep.dtype), vrep,
+            preferred_element_type=jnp.float32)
         return (m_new, l_new, acc), None
 
     init = (

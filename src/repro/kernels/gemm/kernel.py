@@ -39,8 +39,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.dispatch import tpu_compiler_params
-
+from repro.kernels.dispatch import VMEM_LIMIT_BYTES
 
 def _matmul_kernel(a_ref, b_ref, c_ref, acc_ref, *, n_k: int, ta: bool, tb: bool):
     k = pl.program_id(2)
@@ -142,7 +141,8 @@ def matmul_pallas(
             out_specs=pl.BlockSpec((1, bm, bn), lambda s, i, j, k: (s, i, j)),
             out_shape=jax.ShapeDtypeStruct((split_k, M, N), jnp.float32),
             scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-            compiler_params=tpu_compiler_params(
+            compiler_params=pltpu.CompilerParams(
+                vmem_limit_bytes=VMEM_LIMIT_BYTES,
                 dimension_semantics=(
                     "arbitrary", "parallel", "parallel", "arbitrary"),
             ),
@@ -156,7 +156,8 @@ def matmul_pallas(
             in_specs=[pl.BlockSpec((split_k, bm, bn), lambda i, j: (0, i, j))],
             out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
             out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
-            compiler_params=tpu_compiler_params(
+            compiler_params=pltpu.CompilerParams(
+                vmem_limit_bytes=VMEM_LIMIT_BYTES,
                 dimension_semantics=("parallel", "parallel"),
             ),
             interpret=interpret,
@@ -182,7 +183,8 @@ def matmul_pallas(
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=VMEM_LIMIT_BYTES,
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -246,8 +248,10 @@ def _stream_k_kernel(a_ref, b_ref, p_ref, acc_ref, *, total: int, ipw: int,
 
 def _stream_k_fixup_kernel(counts_ref, p_ref, o_ref, *, slots: int):
     """Masked generalization of `_reduce_kernel`: per tile, sum the first
-    ``counts`` partial slots (the rest were never written) and cast."""
-    cnt = counts_ref[0, 0]
+    ``counts`` partial slots (the rest were never written) and cast.
+    ``counts_ref`` is the whole (tm, tn) table in SMEM: a (1, 1) VMEM
+    block would break the TPU's (8, 128) block-shape rule."""
+    cnt = counts_ref[pl.program_id(0), pl.program_id(1)]
     mask = jax.lax.broadcasted_iota(jnp.int32, (slots, 1, 1), 0) < cnt
     o_ref[...] = jnp.where(mask, p_ref[...], 0.0).sum(axis=0).astype(o_ref.dtype)
 
@@ -315,7 +319,8 @@ def matmul_stream_k(
         out_specs=pl.BlockSpec((1, bm, bn), _p_map),
         out_shape=jax.ShapeDtypeStruct((slots, M, N), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=VMEM_LIMIT_BYTES,
             # both dims sequential: one persistent walk per workgroup
             dimension_semantics=("arbitrary", "arbitrary"),
         ),
@@ -326,12 +331,13 @@ def matmul_stream_k(
         functools.partial(_stream_k_fixup_kernel, slots=slots),
         grid=(tm, tn),
         in_specs=[
-            pl.BlockSpec((1, 1), lambda i, j: (i, j)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((slots, bm, bn), lambda i, j: (0, i, j)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=VMEM_LIMIT_BYTES,
             dimension_semantics=("parallel", "parallel"),
         ),
         interpret=interpret,

@@ -32,8 +32,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.dispatch import tpu_compiler_params
-
+from repro.kernels.dispatch import VMEM_LIMIT_BYTES
 
 # --------------------------------------------------------------------------
 # Homogeneous grouped GEMM
@@ -79,7 +78,8 @@ def grouped_matmul_pallas(
         out_specs=pl.BlockSpec((1, bm, bn), lambda i, j, g, k: (g, i, j)),
         out_shape=jax.ShapeDtypeStruct((G, M, N), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=VMEM_LIMIT_BYTES,
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -145,7 +145,8 @@ def ragged_matmul_pallas(
         functools.partial(_ragged_kernel, n_k=n_k),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((Mtotal, N), out_dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=VMEM_LIMIT_BYTES,
             dimension_semantics=("arbitrary", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -18,12 +18,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.dispatch import tpu_compiler_params
-
+from repro.kernels.dispatch import VMEM_LIMIT_BYTES
 
 def _mamba_kernel(
     xd_ref,    # (1, L, P)  dt * x
-    da_ref,    # (1, L)     dt * A  (log decay)
+    da_ref,    # (1, L, 1)  dt * A  (log decay), one column per chunk
     b_ref,     # (1, L, N)
     c_ref,     # (1, L, N)
     s0_ref,    # (1, N, P)  initial state
@@ -41,23 +40,29 @@ def _mamba_kernel(
         state_ref[...] = s0_ref[0].astype(jnp.float32)
 
     xd = xd_ref[0].astype(jnp.float32)
-    da = da_ref[0].astype(jnp.float32)
+    da = da_ref[0].astype(jnp.float32)   # (L, 1)
     Bm = b_ref[0].astype(jnp.float32)
     Cm = c_ref[0].astype(jnp.float32)
     S_prev = state_ref[...]
 
-    s = jnp.cumsum(da)
-    stot = s[-1]
-    G = jnp.dot(Cm, Bm.T, preferred_element_type=jnp.float32)
     ii = jax.lax.broadcasted_iota(jnp.int32, (L, L), 0)
     jj = jax.lax.broadcasted_iota(jnp.int32, (L, L), 1)
-    logdec = jnp.where(ii >= jj, s[:, None] - s[None, :], -jnp.inf)
+    causal = ii >= jj
+    # Inclusive prefix sum as a lower-triangular matmul: Mosaic has no
+    # cumsum lowering.  HIGHEST keeps the f32 log-decays exact enough to
+    # exponentiate.
+    s = jnp.dot(causal.astype(jnp.float32), da,
+                preferred_element_type=jnp.float32,
+                precision=jax.lax.Precision.HIGHEST)       # (L, 1)
+    stot = jnp.sum(da, axis=0, keepdims=True)              # (1, 1)
+    G = jnp.dot(Cm, Bm.T, preferred_element_type=jnp.float32)
+    logdec = jnp.where(causal, s - s.T, -jnp.inf)
     Y = jnp.dot(G * jnp.exp(logdec), xd, preferred_element_type=jnp.float32)
-    Y += jnp.exp(s)[:, None] * jnp.dot(
+    Y += jnp.exp(s) * jnp.dot(
         Cm, S_prev, preferred_element_type=jnp.float32
     )
     S_new = jnp.exp(stot) * S_prev + jnp.dot(
-        Bm.T, jnp.exp(stot - s)[:, None] * xd,
+        Bm.T, jnp.exp(stot - s) * xd,
         preferred_element_type=jnp.float32,
     )
     state_ref[...] = S_new
@@ -70,7 +75,8 @@ def _mamba_kernel(
 
 def mamba_scan_pallas(
     xd: jax.Array,   # (BH, T, P) — dt*x, T multiple of chunk
-    da: jax.Array,   # (BH, T)    — dt*A
+    da: jax.Array,   # (BH, T, 1) — dt*A, a column so every chunk's block
+                     #   (1, chunk, 1) meets the TPU block-shape rule
     Bm: jax.Array,   # (BH, T, N)
     Cm: jax.Array,   # (BH, T, N)
     s0: jax.Array,   # (BH, N, P)
@@ -88,7 +94,7 @@ def mamba_scan_pallas(
         grid=(BH, n_chunks),
         in_specs=[
             pl.BlockSpec((1, chunk, P), lambda b, c: (b, c, 0)),
-            pl.BlockSpec((1, chunk), lambda b, c: (b, c)),
+            pl.BlockSpec((1, chunk, 1), lambda b, c: (b, c, 0)),
             pl.BlockSpec((1, chunk, N), lambda b, c: (b, c, 0)),
             pl.BlockSpec((1, chunk, N), lambda b, c: (b, c, 0)),
             pl.BlockSpec((1, N, P), lambda b, c: (b, 0, 0)),
@@ -102,7 +108,8 @@ def mamba_scan_pallas(
             jax.ShapeDtypeStruct((BH, N, P), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((N, P), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=VMEM_LIMIT_BYTES,
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -22,8 +22,8 @@ from repro.kernels.mamba_scan.ref import (
 )
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
-def _ssd(xd, da, Bm, Cm, chunk, interpret):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def _ssd(xd, da, Bm, Cm, s0, chunk, interpret):
     Bsz, T, H, P = xd.shape
     N = Bm.shape[-1]
     Tp = -(-T // chunk) * chunk
@@ -38,25 +38,26 @@ def _ssd(xd, da, Bm, Cm, chunk, interpret):
         return t.reshape(Bsz * H, Tp, *feat)
 
     xdf = prep(xd, (P,))
-    daf = prep(da[..., None], (1,))[..., 0]
+    daf = prep(da[..., None], (1,))
     Bf = prep(Bm, (N,))
     Cf = prep(Cm, (N,))
-    s0 = jnp.zeros((Bsz * H, N, P), f32)
     y, s_final = mamba_scan_pallas(
-        xdf, daf, Bf, Cf, s0, chunk=chunk, interpret=interpret
+        xdf, daf, Bf, Cf, s0.astype(f32).reshape(Bsz * H, N, P),
+        chunk=chunk, interpret=interpret,
     )
     y = y.reshape(Bsz, H, Tp, P)[:, :, :T].transpose(0, 2, 1, 3)
     return y.astype(xd.dtype), s_final.reshape(Bsz, H, N, P)
 
 
-def _ssd_fwd(xd, da, Bm, Cm, chunk, interpret):
-    return _ssd(xd, da, Bm, Cm, chunk, interpret), (xd, da, Bm, Cm)
+def _ssd_fwd(xd, da, Bm, Cm, s0, chunk, interpret):
+    return _ssd(xd, da, Bm, Cm, s0, chunk, interpret), (xd, da, Bm, Cm, s0)
 
 
 def _ssd_bwd(chunk, interpret, res, g):
-    xd, da, Bm, Cm = res
     _, vjp = jax.vjp(
-        lambda *a: ssd_chunk_ref(*a, chunk=chunk), xd, da, Bm, Cm
+        lambda xd, da, Bm, Cm, s0: ssd_chunk_ref(
+            xd, da, Bm, Cm, chunk=chunk, initial_state=s0),
+        *res,
     )
     return vjp(g)
 
@@ -72,14 +73,18 @@ def ssd_scan(
     interpret: bool | None = None,
     force_ref: bool = False,
 ):
-    """General SSD: xd (B,T,H,P); da (B,T,H); Bm/Cm (B,T,H,N).
+    """General SSD: xd (B,T,H,P); da (B,T,H); Bm/Cm (B,T,H,N);
+    ``initial_state`` (B,H,N,P) or None for zeros.
     Returns (y, final_state)."""
     interp = bool(interpret)  # None → ref path off-TPU, pallas on TPU
-    if force_ref or initial_state is not None or not (use_pallas() or interp):
+    if force_ref or not (use_pallas() or interp):
         return ssd_chunk_ref(
             xd, da, Bm, Cm, chunk=chunk, initial_state=initial_state
         )
-    return _ssd(xd, da, Bm, Cm, chunk, interp)
+    if initial_state is None:
+        Bsz, _, H, P = xd.shape
+        initial_state = jnp.zeros((Bsz, H, Bm.shape[-1], P), jnp.float32)
+    return _ssd(xd, da, Bm, Cm, initial_state, chunk, interp)
 
 
 def scan_for_desc(

@@ -103,10 +103,11 @@ def gqa_apply(
     k = apply_rope(k, positions, cfg.rope_theta)
 
     if cache is None:
-        out = flash_attention(
-            _pin_heads(q.transpose(0, 2, 1, 3), mesh),
-            _pin_heads(k.transpose(0, 2, 1, 3), mesh),
-            _pin_heads(v.transpose(0, 2, 1, 3), mesh),
+        out = _flash_heads(
+            q.transpose(0, 2, 1, 3),
+            k.transpose(0, 2, 1, 3),
+            v.transpose(0, 2, 1, 3),
+            mesh,
             causal=True,
             window=window,
         ).transpose(0, 2, 1, 3)
@@ -126,10 +127,11 @@ def gqa_apply(
             # Prefill: flash attention against the written cache buffer —
             # the dense GEMV path would materialize O(T·S) scores
             # (§Perf prefill iteration 1).
-            out = flash_attention(
+            out = _flash_heads(
                 q.transpose(0, 2, 1, 3),
                 kc.transpose(0, 2, 1, 3),
                 vc.transpose(0, 2, 1, 3),
+                mesh,
                 causal=True,
                 window=window,
                 q_offset=0,  # prefill starts at position 0
@@ -192,26 +194,30 @@ def _pin_batch_only(x, mesh):
     )
 
 
-def _pin_heads(x, mesh):
-    """Pin (B, H, T, D) activations head-sharded over 'model': GSPMD
-    otherwise replicates the flash-attention scan across the model axis —
-    16x redundant attention FLOPs + per-layer QKV gathers
-    (§Perf train iteration T1)."""
-    if mesh is None or "model" not in mesh.axis_names:
-        return x
+def _flash_heads(q, k, v, mesh, **kw):
+    """`flash_attention` on (B, H, T, D) operands with the heads sharded
+    over 'model': one shard_map program per head shard, because the
+    partitioner cannot split the kernel's custom call (and replicating
+    the attention over the model axis would repeat its FLOPs on every
+    device, §Perf train iteration T1).  Heads are independent and each
+    shard keeps the same q-per-kv ratio, so GQA maps stay local."""
+    if mesh is None or mesh.shape.get("model", 1) <= 1:
+        return flash_attention(q, k, v, **kw)
     import numpy as np
-    from jax.sharding import NamedSharding, PartitionSpec as P
+    from jax.sharding import PartitionSpec as P
 
     msize = mesh.shape["model"]
-    if msize <= 1 or x.shape[1] % msize != 0:
-        return x
+    if q.shape[1] % msize or k.shape[1] % msize:
+        return flash_attention(q, k, v, **kw)
     dp = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
-    B = x.shape[0]
+    B = q.shape[0]
     while dp and B % int(np.prod([mesh.shape[a] for a in dp])) != 0:
         dp = dp[:-1]
-    return jax.lax.with_sharding_constraint(
-        x, NamedSharding(mesh, P(dp if dp else None, "model", None, None))
-    )
+    spec = P(dp if dp else None, "model", None, None)
+    return jax.shard_map(
+        lambda q, k, v: flash_attention(q, k, v, **kw), mesh=mesh,
+        in_specs=(spec, spec, spec), out_specs=spec, check_vma=False,
+    )(q, k, v)
 
 
 # =========================================================== MLA attention
@@ -298,10 +304,11 @@ def mla_apply(
             axis=-1,
         )
         q = jnp.concatenate([q_nope, q_rope], axis=-1)
-        out = flash_attention(
-            _pin_heads(q.transpose(0, 2, 1, 3), mesh),
-            _pin_heads(k.transpose(0, 2, 1, 3), mesh),
-            _pin_heads(v.transpose(0, 2, 1, 3), mesh),
+        out = _flash_heads(
+            q.transpose(0, 2, 1, 3),
+            k.transpose(0, 2, 1, 3),
+            v.transpose(0, 2, 1, 3),
+            mesh,
             causal=True,
             scale=scale,
         ).transpose(0, 2, 1, 3)

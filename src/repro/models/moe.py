@@ -32,21 +32,6 @@ from repro.kernels.grouped_gemm import grouped_gemm
 from repro.models.common import mlp_apply, mlp_specs
 from repro.models.spec import Spec
 
-# jax moved shard_map out of experimental and (separately) renamed
-# check_rep -> check_vma; pick location and kwarg independently so every
-# era of the toolchain works.
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-else:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map as _shard_map
-import inspect as _inspect
-
-_CHECK_KW = (
-    "check_vma"
-    if "check_vma" in _inspect.signature(_shard_map).parameters
-    else "check_rep"
-)
-
 
 def moe_specs(cfg: ArchConfig) -> dict:
     E, d, ff = cfg.n_routed_experts, cfg.d_model, cfg.moe_d_ff
@@ -173,12 +158,12 @@ def moe_ep_apply(
         "wd": P(model_axis, None, None),
     }
     x_spec = P(data_axes, model_axis, None)  # tokens seq-sharded for dispatch
-    y, aux = _shard_map(
+    y, aux = jax.shard_map(
         routed,
         mesh=mesh,
         in_specs=(pspec_w, x_spec),
         out_specs=(x_spec, P()),
-        **{_CHECK_KW: False},
+        check_vma=False,
     )(routed_params, x)
     if cfg.n_shared_experts:
         y = y + mlp_apply(p["shared"], x)

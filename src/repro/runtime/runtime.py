@@ -875,6 +875,8 @@ class Runtime:
                 cache_hit=launch.cache_hit,
                 fallback=launch.fallback,
                 graph_ids=_graph_ids(launch.tickets),
+                tiles=tuple(t.key() for t in
+                            (launch.plan.tiles or [launch.plan.tile])),
             ))
             self._feed_calibration(launch, achieved)
         if launches:

@@ -36,6 +36,9 @@ class GroupRecord:
     # entries is the cross-request overlap the dataflow executor exists
     # to create.
     graph_ids: tuple = ()
+    # Keys of the GO tiles the planned launch runs (one per member of a
+    # mixed group, else one).
+    tiles: tuple = ()
 
     @property
     def model_error(self) -> Optional[float]:

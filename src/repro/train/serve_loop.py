@@ -12,12 +12,25 @@ math.  Telemetry then reports CD/mode/plan-cache behaviour for the run.
 """
 from __future__ import annotations
 
-from typing import Any, Callable, Optional, Tuple
+import time
+from typing import Any, Callable, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
 from repro.models.model import Model
+
+
+class Decoded(NamedTuple):
+    """What `greedy_decode` served, with its times split by phase: the
+    compile of the prefill and decode programs, the prefill itself, and
+    the decode loop (every step, runtime dispatch included)."""
+
+    tokens: jax.Array          # (B, steps)
+    prefill_logits: jax.Array  # (B, 1, V) last-prompt-token logits
+    compile_s: float
+    prefill_s: float
+    decode_s: float
 
 
 def make_serve_fns(model: Model) -> Tuple[Callable, Callable]:
@@ -35,7 +48,7 @@ def greedy_decode(
     cache_dtype=jnp.float32, runtime: Optional[Any] = None,
     tenant: str = "default", mixed_ops: bool = False, graph: bool = False,
 ):
-    """Greedy generation for examples/tests (host loop, jitted steps).
+    """Greedy generation (host loop, jitted steps); returns `Decoded`.
 
     ``runtime``: optional `repro.runtime.Runtime`; each decode step's
     QKV/FFN GEMM descriptors are submitted to it and flushed, so the
@@ -53,12 +66,20 @@ def greedy_decode(
     ready — concurrent requests overlap across stage boundaries."""
     B = jax.tree.leaves(prompt_batch)[0].shape[0]
     cache = model.init_cache(batch=B, s_max=s_max, dtype=cache_dtype)
-    prefill = jax.jit(model.prefill)
-    decode = jax.jit(model.decode_step)
+    t0 = time.perf_counter()
+    prefill = jax.jit(model.prefill).lower(params, prompt_batch, cache).compile()
+    compile_s = time.perf_counter() - t0
+    t0 = time.perf_counter()
     logits, cache, length = prefill(params, prompt_batch, cache)
+    prefill_logits = jax.block_until_ready(logits)
+    prefill_s = time.perf_counter() - t0
     cache_len = jnp.asarray(length, jnp.int32)
     out = []
     tok = jnp.argmax(logits[:, -1], axis=-1)[:, None]
+    t0 = time.perf_counter()
+    decode = jax.jit(model.decode_step).lower(
+        params, tok, cache, cache_len).compile()
+    compile_s += time.perf_counter() - t0
     step_requests = step_bundle = step_graph = None
     if runtime is not None and graph:
         from repro.runtime import decode_step_graph
@@ -80,6 +101,7 @@ def greedy_decode(
         # the bundle (incl. the §6.11 fusion decision) is identical every
         # step — derive it once, submit it per step
         step_requests = decode_step_requests(runtime.ctrl, model.cfg, B)
+    t0 = time.perf_counter()
     for _ in range(steps):
         out.append(tok)
         if step_graph is not None:
@@ -98,4 +120,6 @@ def greedy_decode(
                 runtime.drain()
             else:
                 runtime.flush(force=True)
-    return jnp.concatenate(out, axis=1)
+    tokens = jax.block_until_ready(jnp.concatenate(out, axis=1))
+    return Decoded(tokens, prefill_logits, compile_s, prefill_s,
+                   time.perf_counter() - t0)

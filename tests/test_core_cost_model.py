@@ -82,3 +82,17 @@ def test_time_properties(m, n, k, bm, bn, cd):
     assert grp >= lower * 0.999
     # sequential is never faster than one member alone
     assert seq >= iso * 0.999
+
+
+def test_device_spec_table_keyed_by_device_kind():
+    from types import SimpleNamespace
+
+    from repro.core.cost_model import CHIP_SPECS, device_spec
+
+    v5e = SimpleNamespace(platform="tpu", device_kind="TPU v5 lite")
+    assert device_spec(v5e) is CHIP_SPECS["TPU v5 lite"] is DEFAULT_SPEC
+    assert device_spec(SimpleNamespace(platform="cpu", device_kind="cpu")) \
+        is DEFAULT_SPEC
+    with pytest.raises(ValueError, match="TPU v9 imaginary"):
+        device_spec(SimpleNamespace(platform="tpu",
+                                    device_kind="TPU v9 imaginary"))

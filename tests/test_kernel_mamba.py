@@ -4,11 +4,15 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.core.tuner import SCAN_TILES
 from repro.kernels.mamba_scan import (
     mamba_chunk_ref,
     mamba_chunk_scan,
     mamba_scan_ref,
 )
+from repro.kernels.mamba_scan import ops as ssd_ops
+from repro.kernels.mamba_scan.ops import ssd_scan
+from repro.kernels.mamba_scan.ref import ssd_chunk_ref
 
 
 def _inputs(key, B, T, H, P, N, dtype=jnp.float32):
@@ -59,3 +63,53 @@ def test_decay_stability_long_sequence():
     A = A * 10.0  # strong decay
     y, S = mamba_chunk_ref(x, dt, A, Bm, Cm, chunk=128)
     assert bool(jnp.isfinite(y).all()) and bool(jnp.isfinite(S).all())
+
+
+def _general_inputs(key, B, T, H, P, N):
+    ks = jax.random.split(key, 5)
+    xd = jax.random.normal(ks[0], (B, T, H, P))
+    da = -jax.nn.softplus(jax.random.normal(ks[1], (B, T, H)))
+    Bm = jax.random.normal(ks[2], (B, T, H, N)) * 0.5
+    Cm = jax.random.normal(ks[3], (B, T, H, N)) * 0.5
+    S0 = jax.random.normal(ks[4], (B, H, N, P))
+    return xd, da, Bm, Cm, S0
+
+
+@pytest.mark.parametrize("chunk", [t.bm for t in SCAN_TILES])
+def test_ssd_kernel_matches_chunk_ref_every_scan_tile(chunk):
+    """The kernel (column decay layout, matmul prefix sum) agrees with the
+    chunked oracle at every chunk length the tuner can pick."""
+    xd, da, Bm, Cm, _ = _general_inputs(jax.random.PRNGKey(chunk), 1, 1100,
+                                        2, 16, 8)
+    y_ref, S_ref = ssd_chunk_ref(xd, da, Bm, Cm, chunk=chunk)
+    y, S = ssd_scan(xd, da, Bm, Cm, chunk=chunk, interpret=True)
+    np.testing.assert_allclose(y, y_ref, rtol=5e-5, atol=5e-5)
+    np.testing.assert_allclose(S, S_ref, rtol=5e-5, atol=5e-5)
+
+
+def test_ssd_kernel_takes_initial_state(monkeypatch):
+    """``initial_state`` feeds the kernel's state input: the forward never
+    runs the reference, and matches it with the same carried state."""
+    xd, da, Bm, Cm, S0 = _general_inputs(jax.random.PRNGKey(5), 2, 160, 2,
+                                         16, 8)
+    y_ref, S_ref = ssd_chunk_ref(xd, da, Bm, Cm, chunk=32, initial_state=S0)
+
+    def no_ref(*a, **k):
+        raise AssertionError("forward fell back to the reference")
+
+    monkeypatch.setattr(ssd_ops, "ssd_chunk_ref", no_ref)
+    y, S = ssd_scan(xd, da, Bm, Cm, chunk=32, initial_state=S0,
+                    interpret=True)
+    np.testing.assert_allclose(y, y_ref, rtol=5e-5, atol=5e-5)
+    np.testing.assert_allclose(S, S_ref, rtol=5e-5, atol=5e-5)
+
+
+def test_ssd_kernel_initial_state_grad():
+    xd, da, Bm, Cm, S0 = _general_inputs(jax.random.PRNGKey(6), 1, 96, 2,
+                                         16, 8)
+    f = lambda s0: ssd_scan(xd, da, Bm, Cm, chunk=32, initial_state=s0,
+                            interpret=True)[0].sum()
+    fr = lambda s0: ssd_chunk_ref(xd, da, Bm, Cm, chunk=32,
+                                  initial_state=s0)[0].sum()
+    np.testing.assert_allclose(jax.grad(f)(S0), jax.grad(fr)(S0),
+                               rtol=1e-4, atol=1e-4)

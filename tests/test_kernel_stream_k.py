@@ -150,3 +150,22 @@ def test_gemm_plain_tile_ragged_bitwise(ta, tb):
     out = gemm(a, b, ta=ta, tb=tb, tile=TileConfig(64, 64, 64),
                interpret=True)
     assert jnp.array_equal(out, gemm_ref(a, b, ta=ta, tb=tb)), (ta, tb)
+
+
+@pytest.mark.parametrize("grid_g", [4, 5])
+def test_stream_k_fixup_reads_each_tiles_count(grid_g):
+    """The fixup pass reads its tile's own contributor count from the
+    SMEM table: on a grid whose counts differ across both tile axes, the
+    kernel is bitwise-equal to the span-walk oracle."""
+    M, N, K = 24, 256, 640
+    bm, bn, bk = 8, 128, 128
+    counts = stream_k_geometry(M // bm, N // bn, K // bk, grid_g)[3]
+    assert len(np.unique(counts)) > 1
+    a, b = _operands(grid_g, M, N, K, False, False)
+    out = matmul_stream_k(a, b, ta=False, tb=False, bm=bm, bn=bn, bk=bk,
+                          grid_g=grid_g, out_dtype=jnp.float32,
+                          interpret=True)
+    mirror = gemm_stream_k_ref(a, b, bm=bm, bn=bn, bk=bk, grid_g=grid_g,
+                               out_dtype=jnp.float32)
+    assert jnp.array_equal(out, mirror)
+    assert jnp.array_equal(out, gemm_ref(a, b, out_dtype=jnp.float32))

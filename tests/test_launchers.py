@@ -38,7 +38,7 @@ def test_serve_launcher_end_to_end():
     toks = main([
         "--arch", "qwen3-14b", "--reduced", "--batch", "2",
         "--prompt-len", "8", "--gen", "4",
-    ])
+    ]).decoded.tokens
     assert toks.shape == (2, 4)
     assert bool(jnp.isfinite(toks).all())
 
@@ -87,3 +87,14 @@ def test_grad_accumulation_matches_single_batch():
     l4 = jax.tree.leaves(st4.params)
     for a, b in zip(l1, l4):
         np.testing.assert_allclose(a, b, rtol=1e-3, atol=2.1e-3)
+
+
+def test_compile_cache_dir_honours_env_else_fixed_repo_path():
+    from pathlib import Path
+
+    from repro.launch.compile_cache import REPO_CACHE_DIR, compile_cache_dir
+
+    assert compile_cache_dir({"JAX_COMPILATION_CACHE_DIR": "/elsewhere"}) is None
+    assert compile_cache_dir({}) == REPO_CACHE_DIR
+    assert compile_cache_dir({"JAX_COMPILATION_CACHE_DIR": ""}) == REPO_CACHE_DIR
+    assert REPO_CACHE_DIR == Path(__file__).resolve().parents[1] / ".jax_cache"
