@@ -6,7 +6,8 @@
 Weights are random (seeded) and made in bf16 directly on the devices that
 hold them.  ``--runtime`` routes each decode step's QKV/FFN GEMMs through
 the online concurrency runtime (`repro.runtime`, DESIGN.md §10) and prints
-its telemetry summary (CD / mode mix / plan-cache hit rate) after the run.
+its telemetry summary (CD / mode mix / plan-cache hit rate, host µs per
+dispatch phase, queue wait) after the run.
 """
 from __future__ import annotations
 

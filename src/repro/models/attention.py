@@ -119,8 +119,9 @@ def gqa_apply(
         # layer (§Perf decode iteration 4 — the winning move).
         k = _pin_batch_only(k.astype(cache.k.dtype), mesh)
         v = _pin_batch_only(v.astype(cache.v.dtype), mesh)
-        kc = jax.lax.dynamic_update_slice_in_dim(cache.k, k, cache_len, axis=1)
-        vc = jax.lax.dynamic_update_slice_in_dim(cache.v, v, cache_len, axis=1)
+        with jax.named_scope("kv_write"):
+            kc = jax.lax.dynamic_update_slice_in_dim(cache.k, k, cache_len, axis=1)
+            vc = jax.lax.dynamic_update_slice_in_dim(cache.v, v, cache_len, axis=1)
         kc, vc = _pin_cache(kc, mesh), _pin_cache(vc, mesh)
         new_cache = KVCache(kc, vc)
         if T > 1:
@@ -315,14 +316,15 @@ def mla_apply(
         if cache is not None:
             ckv_w = _pin_batch_only(ckv.astype(cache.ckv.dtype), mesh)
             krope_w = _pin_batch_only(krope.astype(cache.krope.dtype), mesh)
-            new_cache = MLACache(
-                jax.lax.dynamic_update_slice_in_dim(
-                    cache.ckv, ckv_w, cache_len, axis=1
-                ),
-                jax.lax.dynamic_update_slice_in_dim(
-                    cache.krope, krope_w, cache_len, axis=1
-                ),
-            )
+            with jax.named_scope("kv_write"):
+                new_cache = MLACache(
+                    jax.lax.dynamic_update_slice_in_dim(
+                        cache.ckv, ckv_w, cache_len, axis=1
+                    ),
+                    jax.lax.dynamic_update_slice_in_dim(
+                        cache.krope, krope_w, cache_len, axis=1
+                    ),
+                )
         else:
             new_cache = None
     else:
@@ -330,12 +332,13 @@ def mla_apply(
         # latents resharded to the cache layout before the write (see GQA).
         ckv_w = _pin_batch_only(ckv.astype(cache.ckv.dtype), mesh)
         krope_w = _pin_batch_only(krope.astype(cache.krope.dtype), mesh)
-        ckv_c = jax.lax.dynamic_update_slice_in_dim(
-            cache.ckv, ckv_w, cache_len, axis=1
-        )
-        krope_c = jax.lax.dynamic_update_slice_in_dim(
-            cache.krope, krope_w, cache_len, axis=1
-        )
+        with jax.named_scope("kv_write"):
+            ckv_c = jax.lax.dynamic_update_slice_in_dim(
+                cache.ckv, ckv_w, cache_len, axis=1
+            )
+            krope_c = jax.lax.dynamic_update_slice_in_dim(
+                cache.krope, krope_w, cache_len, axis=1
+            )
         ckv_c, krope_c = _pin_cache(ckv_c, mesh), _pin_cache(krope_c, mesh)
         new_cache = MLACache(ckv_c, krope_c)
         wuk = p["wuk"].reshape(r, h, dn)

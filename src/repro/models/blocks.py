@@ -68,40 +68,42 @@ def attn_block_apply(
     moe_capacity_factor: float = 1.25,
 ):
     h = rms_norm(p["attn_norm"], x, cfg.norm_eps)
-    if cfg.attn_type == "mla":
-        a, new_cache = mla_apply(
-            p["attn"], h, cfg, positions, cache=cache, cache_len=cache_len,
-            mesh=mesh,
-        )
-    else:
-        a, new_cache = gqa_apply(
-            p["attn"], h, cfg, positions, window=window,
-            cache=cache, cache_len=cache_len, mesh=mesh,
-        )
+    with jax.named_scope("attn"):
+        if cfg.attn_type == "mla":
+            a, new_cache = mla_apply(
+                p["attn"], h, cfg, positions, cache=cache, cache_len=cache_len,
+                mesh=mesh,
+            )
+        else:
+            a, new_cache = gqa_apply(
+                p["attn"], h, cfg, positions, window=window,
+                cache=cache, cache_len=cache_len, mesh=mesh,
+            )
     x = x + a
     h = rms_norm(p["mlp_norm"], x, cfg.norm_eps)
     aux = jnp.zeros((), jnp.float32)
-    if moe:
-        # EP dispatch shards tokens over the model axis; at decode (T == 1,
-        # indivisible) the cheap capacity path runs instead (GSPMD shards the
-        # expert einsum over E and inserts the combine collectives).
-        use_ep = moe_mode == "ep" or (
-            moe_mode == "auto" and mesh is not None
-            and "model" in mesh.axis_names and mesh.shape["model"] > 1
-            and h.shape[1] % mesh.shape["model"] == 0
-        )
-        if use_ep:
-            m, aux = moe_ep_apply(
-                p["moe"], h, cfg, mesh,
-                capacity_factor=moe_capacity_factor,
-                data_axes=tuple(a for a in mesh.axis_names if a != "model"),
+    with jax.named_scope("mlp"):
+        if moe:
+            # EP dispatch shards tokens over the model axis; at decode (T == 1,
+            # indivisible) the cheap capacity path runs instead (GSPMD shards the
+            # expert einsum over E and inserts the combine collectives).
+            use_ep = moe_mode == "ep" or (
+                moe_mode == "auto" and mesh is not None
+                and "model" in mesh.axis_names and mesh.shape["model"] > 1
+                and h.shape[1] % mesh.shape["model"] == 0
             )
+            if use_ep:
+                m, aux = moe_ep_apply(
+                    p["moe"], h, cfg, mesh,
+                    capacity_factor=moe_capacity_factor,
+                    data_axes=tuple(a for a in mesh.axis_names if a != "model"),
+                )
+            else:
+                m, aux = moe_capacity_apply(
+                    p["moe"], h, cfg, capacity_factor=moe_capacity_factor
+                )
         else:
-            m, aux = moe_capacity_apply(
-                p["moe"], h, cfg, capacity_factor=moe_capacity_factor
-            )
-    else:
-        m = mlp_apply(p["mlp"], h)
+            m = mlp_apply(p["mlp"], h)
     return x + m, new_cache, aux
 
 

@@ -130,6 +130,7 @@ class Model:
                    layer_offset):
         cfg = self.cfg
 
+        @jax.named_scope("block")
         def body(carry, inp):
             x, i = carry
             p, c = inp
@@ -184,15 +185,17 @@ class Model:
                 body,
                 policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
             )
-        (x, _), (new_cache, auxs) = jax.lax.scan(
-            body, (x, layer_offset), (stack, cache)
-        )
+        with jax.named_scope("layers"):
+            (x, _), (new_cache, auxs) = jax.lax.scan(
+                body, (x, layer_offset), (stack, cache)
+            )
         return x, new_cache, auxs.sum()
 
     def _scan_zamba(self, params, x, positions, cache, cache_len):
         cfg = self.cfg
         shared = params["shared"]
 
+        @jax.named_scope("block")
         def body(carry, inp):
             x, i = carry
             p, c = inp
@@ -204,14 +207,16 @@ class Model:
 
         if self.remat == "full":
             body = jax.checkpoint(body)
-        (x, _), new_cache = jax.lax.scan(
-            body, (x, 0), (params["layers"], cache)
-        )
+        with jax.named_scope("layers"):
+            (x, _), new_cache = jax.lax.scan(
+                body, (x, 0), (params["layers"], cache)
+            )
         return x, new_cache
 
     def _scan_xlstm(self, params, x, cache):
         cfg = self.cfg
 
+        @jax.named_scope("block")
         def body(x, inp):
             p, c = inp
             y, new_c = B.xlstm_group_apply(p, x, cfg, cache=c)
@@ -219,7 +224,8 @@ class Model:
 
         if self.remat == "full":
             body = jax.checkpoint(body)
-        x, new_cache = jax.lax.scan(body, x, (params["layers"], cache))
+        with jax.named_scope("layers"):
+            x, new_cache = jax.lax.scan(body, x, (params["layers"], cache))
         return x, new_cache
 
     # ----------------------------------------------------------- training
@@ -228,7 +234,8 @@ class Model:
         Bsz, T = x.shape[:2]
         positions = jnp.broadcast_to(jnp.arange(T)[None], (Bsz, T))
         x, _, aux = self._run_layers(params, x, positions)
-        logits = lm_head_apply(params["embed"], x)
+        with jax.named_scope("lm_head"):
+            logits = lm_head_apply(params["embed"], x)
         return logits, aux
 
     def loss(self, params, batch):
@@ -295,6 +302,7 @@ class Model:
             }
         raise ValueError(fam)
 
+    @jax.named_scope("prefill")
     def prefill(self, params, batch, cache):
         """Feed a prompt; returns (last-token logits, cache, new length)."""
         x = self._embed_inputs(params, batch)
@@ -305,9 +313,11 @@ class Model:
             params, x, positions, cache=self._wrap_cache(cache),
             cache_len=cache_len,
         )
-        logits = lm_head_apply(params["embed"], x[:, -1:])
+        with jax.named_scope("lm_head"):
+            logits = lm_head_apply(params["embed"], x[:, -1:])
         return logits, self._unwrap_cache(new_cache, cache), T
 
+    @jax.named_scope("decode_step")
     def decode_step(self, params, tokens, cache, cache_len):
         """One-token step.  tokens (B, 1) (or frames (B,1,D) for audio)."""
         cfg = self.cfg
@@ -321,7 +331,8 @@ class Model:
             params, x, positions, cache=self._wrap_cache(cache),
             cache_len=cache_len,
         )
-        logits = lm_head_apply(params["embed"], x)
+        with jax.named_scope("lm_head"):
+            logits = lm_head_apply(params["embed"], x)
         return logits, self._unwrap_cache(new_cache, cache), cache_len + 1
 
     # ---------------------------------------------------- cache shardings

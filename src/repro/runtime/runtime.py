@@ -33,6 +33,11 @@ accounting works identically in closed-loop replay (virtual clock, the
 serving benchmark) and live shadow dispatch (wall clock, the serve loop).
 Set ``RuntimeConfig.execute=True`` to also run every launch through the
 real pallas kernels (`ConcurrencyController.execute_plan`).
+
+Each phase of the dispatch path is a `jax.profiler` span
+(``runtime.submit``, ``runtime.plan``, ``runtime.launch``,
+``runtime.record``), so a profiler trace places it on the device's
+clock, and its host-clock time is summed in `Telemetry.host_s`.
 """
 from __future__ import annotations
 
@@ -45,6 +50,7 @@ from dataclasses import dataclass, field, replace
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 
 from repro.core.cost_model import (
     EVAL_COUNTER,
@@ -176,6 +182,8 @@ class Ticket:
     members: Optional[List["Ticket"]] = field(default=None, repr=False)
     nodes: Optional[Dict[str, "Ticket"]] = field(default=None, repr=False)
     state: Optional[GraphState] = field(default=None, repr=False)
+    # Host clock at submit (a graph node: at release), for the queue wait.
+    host_t: float = field(default_factory=time.perf_counter, repr=False)
 
     @property
     def desc(self) -> GemmDesc:
@@ -235,6 +243,7 @@ class Launch:
     # and the modeled device time the failed attempts consumed.
     fallback: Optional[str] = None
     penalty_s: float = 0.0
+    achieved_s: Optional[float] = None   # wall clock, when executed
 
 
 class _ClassQueue:
@@ -420,11 +429,16 @@ class Runtime:
         `integration.submit_decode_bundle`) survive as deprecation
         wrappers around this method.
         """
-        if isinstance(work, OpGraph):
-            return self._submit_graph(work, tenant, now)
-        if isinstance(work, (list, tuple)):
-            return self._submit_bundle(work, tenant, now)
-        return self._submit_one(work, tenant, now)
+        t0 = time.perf_counter()
+        with TraceAnnotation("runtime.submit"):
+            if isinstance(work, OpGraph):
+                ticket = self._submit_graph(work, tenant, now)
+            elif isinstance(work, (list, tuple)):
+                ticket = self._submit_bundle(work, tenant, now)
+            else:
+                ticket = self._submit_one(work, tenant, now)
+        self.telemetry.record_host("submit", time.perf_counter() - t0)
+        return ticket
 
     def _submit_one(
         self,
@@ -588,6 +602,7 @@ class Runtime:
         gnode = state.graph.nodes[name]
         tk = state.tickets[name]
         tk.submit_t = max(tk.submit_t, now)
+        tk.host_t = time.perf_counter()
         tk.request = bind_operands(gnode.desc, state.operands_for(name),
                                    tag=gnode.tag or name)
         weight = self.tenant_slo(handle.tenant).weight
@@ -727,165 +742,189 @@ class Runtime:
         ``flush_budget_s`` binds only a prefix of that order — the rest
         requeue with their original deadlines, so a monolithic tenant's
         backlog yields the device at every flush boundary.
+
+        Three phases, each a span and a `Telemetry.host_s` entry: ``plan``
+        (ripe classes, their queues, the plan-cache probe or planning),
+        ``launch`` (the budget cut, binding to the timeline, execution)
+        and ``record`` (group records and calibration).  A ticket's queue
+        wait runs from its submit to the end of ``plan``.
         """
         now = self.clock() if now is None else now
         evals0 = EVAL_COUNTER.evals
         resorts0 = self.telemetry.sig_resorts
-        ripe = [
-            k for k in self._order
-            if self._queues.get(k)
-            and (force or now - self._queues[k].oldest_t >= self.config.window_s)
-        ]
-        if not ripe:
-            return []
-        self._flush_id += 1
-        self.telemetry.record_flush(self.queue_depths())
+        t0 = time.perf_counter()
+        with TraceAnnotation("runtime.plan"):
+            ripe = [
+                k for k in self._order
+                if self._queues.get(k)
+                and (force or now - self._queues[k].oldest_t >= self.config.window_s)
+            ]
+            if not ripe:
+                self.telemetry.record_host("plan", time.perf_counter() - t0)
+                return []
+            self._flush_id += 1
+            self.telemetry.record_flush(self.queue_depths())
 
-        edf = self.config.policy == "edf"
-        if edf:
-            # Earliest-deadline class first; deadlines are absolute, so a
-            # waiting class only rises in this order — no starvation.
-            rotated = sorted(ripe, key=lambda k: (
-                self._queues[k].min_deadline, -self._queues[k].max_weight, k))
-        else:
-            # Rotate so each flush starts service at a different class
-            # (fairness).
-            start = self._rr % max(len(self._order), 1)
-            rotated = [k for k in self._order[start:] + self._order[:start]
-                       if k in ripe]
-            self._rr = (self._order.index(rotated[0]) + 1) % len(self._order)
+            edf = self.config.policy == "edf"
+            if edf:
+                # Earliest-deadline class first; deadlines are absolute, so a
+                # waiting class only rises in this order — no starvation.
+                rotated = sorted(ripe, key=lambda k: (
+                    self._queues[k].min_deadline, -self._queues[k].max_weight, k))
+            else:
+                # Rotate so each flush starts service at a different class
+                # (fairness).
+                start = self._rr % max(len(self._order), 1)
+                rotated = [k for k in self._order[start:] + self._order[:start]
+                           if k in ripe]
+                self._rr = (self._order.index(rotated[0]) + 1) % len(self._order)
 
-        per_class: List[List[Launch]] = []
-        planning_s = 0.0
-        for key in rotated:
-            # Tickets come back already canonically ordered and the
-            # signature keys are maintained incrementally — no sort, no
-            # per-flush signature rebuild (telemetry.sig_resorts counts
-            # any future regression to a full re-sort).
-            tickets, sig_keys = self._queues[key].take_all()
-            if key == MIXED_CLASS:
-                # Ready-set depth (§19.3): how many graph nodes this
-                # concurrency window could draw from — the dataflow
-                # executor's analogue of queue depth.
-                depth = sum(1 for t in tickets
-                            if t.kind == "node" or
-                            (t.parent is not None
-                             and t.parent.kind == "node"))
-                if depth:
-                    self.telemetry.record_ready_depth(depth)
-                ranks = [t.rank for t in tickets] if edf else None
-                if ranks is not None and len(set(ranks)) > 1:
-                    # Rank-aware chunking changes the plan, so the rank
-                    # pattern joins the signature; tenant ranks are
-                    # static, so steady-state traffic still hits.
-                    sched, hit = self._plan_for_keys(
-                        (MIXED_CLASS,) + sig_keys
-                        + ("ranks:" + "".join(map(str, ranks)),),
-                        lambda: [t.desc for t in tickets],
-                        planner=lambda descs, available: self.ctrl.plan_mixed(
-                            descs, available=available, ranks=ranks))
+            per_class: List[List[Launch]] = []
+            planning_s = 0.0
+            for key in rotated:
+                # Tickets come back already canonically ordered and the
+                # signature keys are maintained incrementally — no sort, no
+                # per-flush signature rebuild (telemetry.sig_resorts counts
+                # any future regression to a full re-sort).
+                tickets, sig_keys = self._queues[key].take_all()
+                if key == MIXED_CLASS:
+                    # Ready-set depth (§19.3): how many graph nodes this
+                    # concurrency window could draw from — the dataflow
+                    # executor's analogue of queue depth.
+                    depth = sum(1 for t in tickets
+                                if t.kind == "node" or
+                                (t.parent is not None
+                                 and t.parent.kind == "node"))
+                    if depth:
+                        self.telemetry.record_ready_depth(depth)
+                    ranks = [t.rank for t in tickets] if edf else None
+                    if ranks is not None and len(set(ranks)) > 1:
+                        # Rank-aware chunking changes the plan, so the rank
+                        # pattern joins the signature; tenant ranks are
+                        # static, so steady-state traffic still hits.
+                        sched, hit = self._plan_for_keys(
+                            (MIXED_CLASS,) + sig_keys
+                            + ("ranks:" + "".join(map(str, ranks)),),
+                            lambda: [t.desc for t in tickets],
+                            planner=lambda descs, available: self.ctrl.plan_mixed(
+                                descs, available=available, ranks=ranks))
+                    else:
+                        sched, hit = self._plan_for_keys(
+                            (MIXED_CLASS,) + sig_keys,
+                            lambda: [t.desc for t in tickets],
+                            planner=self.ctrl.plan_mixed)
                 else:
                     sched, hit = self._plan_for_keys(
-                        (MIXED_CLASS,) + sig_keys,
-                        lambda: [t.desc for t in tickets],
-                        planner=self.ctrl.plan_mixed)
+                        sig_keys, lambda: [t.desc for t in tickets])
+                self.telemetry.record_plan(hit, CP_OVERHEAD_S)
+                if not hit:
+                    planning_s += CP_OVERHEAD_S
+                per_class.append([
+                    Launch(plan=gp, tickets=[tickets[i] for i in gp.indices],
+                           class_key=key, cache_hit=hit)
+                    for gp in sched.groups
+                ])
+
+            if edf:
+                launches = [ln for groups in per_class for ln in groups]
+                launches.sort(key=lambda ln: (
+                    min(tk.deadline_t for tk in ln.tickets),
+                    -max(self.tenant_slo(tk.tenant).weight for tk in ln.tickets),
+                    min(tk.seq for tk in ln.tickets)))
             else:
-                sched, hit = self._plan_for_keys(
-                    sig_keys, lambda: [t.desc for t in tickets])
-            self.telemetry.record_plan(hit, CP_OVERHEAD_S)
-            if not hit:
-                planning_s += CP_OVERHEAD_S
-            per_class.append([
-                Launch(plan=gp, tickets=[tickets[i] for i in gp.indices],
-                       class_key=key, cache_hit=hit)
-                for gp in sched.groups
-            ])
-
-        if edf:
-            launches = [ln for groups in per_class for ln in groups]
-            launches.sort(key=lambda ln: (
-                min(tk.deadline_t for tk in ln.tickets),
-                -max(self.tenant_slo(tk.tenant).weight for tk in ln.tickets),
-                min(tk.seq for tk in ln.tickets)))
-        else:
-            launches = _interleave(per_class)
-
-        # Budgeted (preemptible) flush §17.3: the budget is a COMMIT
-        # HORIZON — a flush may bind launches only until the modeled
-        # device is committed through ``now + flush_budget_s``.  Work
-        # past the horizon requeues (deadlines intact), so later
-        # flushes re-order it against whatever arrived meanwhile: this
-        # is what keeps a sliced prefill preemptible instead of merely
-        # chopped.  If the device is already committed past the horizon
-        # nothing binds this flush; otherwise at least one launch does
-        # (even one that overshoots), so forced flushing makes progress.
-        base = max(self.device_free_t, now + planning_s)
-        budget = self.config.flush_budget_s
-        if budget is not None:
-            horizon = now + budget
-            acc, cut = base, 0
-            for launch in launches:
-                if cut == 0:
-                    # Only prior *committed* work blocks the first launch;
-                    # planning overhead may overshoot (a forced flush on an
-                    # idle device must always make progress, or drain spins).
-                    if self.device_free_t > horizon:
+                launches = _interleave(per_class)
+        bound = time.perf_counter()
+        with TraceAnnotation("runtime.launch"):
+            # Budgeted (preemptible) flush §17.3: the budget is a COMMIT
+            # HORIZON — a flush may bind launches only until the modeled
+            # device is committed through ``now + flush_budget_s``.  Work
+            # past the horizon requeues (deadlines intact), so later
+            # flushes re-order it against whatever arrived meanwhile: this
+            # is what keeps a sliced prefill preemptible instead of merely
+            # chopped.  If the device is already committed past the horizon
+            # nothing binds this flush; otherwise at least one launch does
+            # (even one that overshoots), so forced flushing makes progress.
+            base = max(self.device_free_t, now + planning_s)
+            budget = self.config.flush_budget_s
+            if budget is not None:
+                horizon = now + budget
+                acc, cut = base, 0
+                for launch in launches:
+                    if cut == 0:
+                        # Only prior *committed* work blocks the first launch;
+                        # planning overhead may overshoot (a forced flush on an
+                        # idle device must always make progress, or drain spins).
+                        if self.device_free_t > horizon:
+                            break
+                    elif acc + _launch_cost(launch) > horizon:
                         break
-                elif acc + _launch_cost(launch) > horizon:
-                    break
-                acc += _launch_cost(launch)
-                cut += 1
-            if cut < len(launches):
-                for launch in launches[cut:]:
-                    self._requeue(launch)
-                self.telemetry.record_deferred(len(launches) - cut)
-                launches = launches[:cut]
+                    acc += _launch_cost(launch)
+                    cut += 1
+                if cut < len(launches):
+                    for launch in launches[cut:]:
+                        self._requeue(launch)
+                    self.telemetry.record_deferred(len(launches) - cut)
+                    launches = launches[:cut]
 
-        # Modeled single-device timeline; real execution optionally rides it.
-        # Planning cost (cache misses) is hidden behind prior kernels when
-        # the device is busy (§6.5) but delays dispatch when it is idle —
-        # this is where the plan cache buys measurable latency.
-        t = base
-        for launch in launches:
-            launch.start_t = t
-            achieved = self._execute(launch) if self.config.execute else None
-            # Fallback attempts consume modeled device time too (§18.2):
-            # `penalty_s` stays 0.0 whenever the planned schedule
-            # succeeded, so the healthy timeline is bitwise-identical to
-            # the unhardened one.
-            t += _launch_cost(launch) + launch.penalty_s
-            launch.end_t = t
-            for ticket in launch.tickets:
-                ticket.done_t = launch.end_t
-                ticket.plan = launch.plan
-                self._finish(ticket)
-            # §6.11 fusion happens before admission (one wide request with a
-            # "-fused" tag); surface it in telemetry instead of "single".
-            mode = launch.plan.mode
-            if mode == "single" and launch.tickets[0].request.tag.endswith("-fused"):
-                mode = "fused"
-            self.telemetry.record_group(GroupRecord(
-                flush_id=self._flush_id,
-                class_key=launch.class_key,
-                tenants=[tk.tenant for tk in launch.tickets],
-                cd=launch.plan.cd,
-                mode=mode,
-                modeled_time_s=launch.plan.modeled_time_s,
-                achieved_time_s=achieved,
-                cache_hit=launch.cache_hit,
-                fallback=launch.fallback,
-                graph_ids=_graph_ids(launch.tickets),
-                tiles=tuple(t.key() for t in
-                            (launch.plan.tiles or [launch.plan.tile])),
-            ))
-            self._feed_calibration(launch, achieved)
-        if launches:
-            self.device_free_t = t
-        self._queue_stale_retunes()
-        self.telemetry.record_flush_fastpath(
-            EVAL_COUNTER.evals - evals0,
-            self.telemetry.sig_resorts - resorts0,
-        )
+            # Modeled single-device timeline; real execution optionally rides it.
+            # Planning cost (cache misses) is hidden behind prior kernels when
+            # the device is busy (§6.5) but delays dispatch when it is idle —
+            # this is where the plan cache buys measurable latency.
+            t = base
+            oldest = bound
+            submitted, waits = 0.0, 0
+            for launch in launches:
+                launch.start_t = t
+                if self.config.execute:
+                    launch.achieved_s = self._execute(launch)
+                # Fallback attempts consume modeled device time too (§18.2):
+                # `penalty_s` stays 0.0 whenever the planned schedule
+                # succeeded, so the healthy timeline is bitwise-identical to
+                # the unhardened one.
+                t += _launch_cost(launch) + launch.penalty_s
+                launch.end_t = t
+                waits += len(launch.tickets)
+                for ticket in launch.tickets:
+                    ticket.done_t = launch.end_t
+                    ticket.plan = launch.plan
+                    self._finish(ticket)
+                    host_t = ticket.host_t
+                    submitted += host_t
+                    if host_t < oldest:
+                        oldest = host_t
+            if launches:
+                self.device_free_t = t
+        t2 = time.perf_counter()
+        with TraceAnnotation("runtime.record"):
+            for launch in launches:
+                # §6.11 fusion happens before admission (one wide request with a
+                # "-fused" tag); surface it in telemetry instead of "single".
+                mode = launch.plan.mode
+                if mode == "single" and launch.tickets[0].request.tag.endswith("-fused"):
+                    mode = "fused"
+                self.telemetry.record_group(GroupRecord(
+                    flush_id=self._flush_id,
+                    class_key=launch.class_key,
+                    tenants=[tk.tenant for tk in launch.tickets],
+                    cd=launch.plan.cd,
+                    mode=mode,
+                    modeled_time_s=launch.plan.modeled_time_s,
+                    achieved_time_s=launch.achieved_s,
+                    cache_hit=launch.cache_hit,
+                    fallback=launch.fallback,
+                    graph_ids=_graph_ids(launch.tickets),
+                    tiles=tuple(t.key() for t in
+                                (launch.plan.tiles or [launch.plan.tile])),
+                ))
+                self._feed_calibration(launch, launch.achieved_s)
+            self._queue_stale_retunes()
+            self.telemetry.record_flush_fastpath(
+                EVAL_COUNTER.evals - evals0,
+                self.telemetry.sig_resorts - resorts0,
+            )
+        self.telemetry.record_flush_host(
+            bound - t0, t2 - bound, time.perf_counter() - t2,
+            waits, waits * bound - submitted, bound - oldest)
         return launches
 
     def drain(self, now: float | None = None) -> List[Launch]:

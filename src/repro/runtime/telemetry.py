@@ -8,6 +8,12 @@ histograms per compatibility class.
 
 Everything is plain Python so the telemetry can run inside the dispatch
 path without touching the device.
+
+Two clocks meet here.  Latencies (`tenant_lat`, `GroupRecord` times) run
+on the runtime's own clock, which replay drives virtually, so they are
+modeled.  The host cost of the dispatch path (`host_s`, `host_calls`)
+and the queue wait (`queue_wait_s`) always run on `time.perf_counter`:
+they are what a decode step really pays on the host.
 """
 from __future__ import annotations
 
@@ -15,6 +21,8 @@ import math
 from collections import Counter
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional
+
+PHASES = ("submit", "plan", "launch", "record")   # of the dispatch path, in order
 
 
 @dataclass
@@ -100,6 +108,15 @@ class Telemetry:
     graph_nodes: int = 0
     ready_depth_hist: Counter = field(default_factory=Counter)
     max_ready_depth: int = 0
+    # Host clock (`time.perf_counter`), also under a virtual runtime
+    # clock: seconds and calls per phase of the dispatch path ("submit",
+    # "plan", "launch", "record"), and the wait from a ticket's submit
+    # to the flush that binds it.  Sums, so nothing grows per launch.
+    host_s: Dict[str, float] = field(default_factory=lambda: dict.fromkeys(PHASES, 0.0))
+    host_calls: Dict[str, int] = field(default_factory=lambda: dict.fromkeys(PHASES, 0))
+    queue_wait_s: float = 0.0
+    queue_waits: int = 0
+    queue_wait_max_s: float = 0.0
 
     # ------------------------------------------------------------- record
     def record_submit(self, n: int = 1) -> None:
@@ -192,6 +209,30 @@ class Telemetry:
         if depth > self.max_ready_depth:
             self.max_ready_depth = depth
 
+    def record_host(self, phase: str, seconds: float) -> None:
+        """One call of a dispatch-path phase took ``seconds`` of host time."""
+        self.host_s[phase] += seconds
+        self.host_calls[phase] += 1
+
+    def record_flush_host(self, plan_s: float, launch_s: float, record_s: float,
+                          waits: int, wait_s: float, wait_max_s: float) -> None:
+        """One flush that bound launches, on the host clock: its seconds
+        by phase, and the ``waits`` tickets it bound, which waited
+        ``wait_s`` seconds in all since their submit, the longest
+        ``wait_max_s``."""
+        host, calls = self.host_s, self.host_calls
+        host["plan"] += plan_s
+        host["launch"] += launch_s
+        host["record"] += record_s
+        calls["plan"] += 1
+        calls["launch"] += 1
+        calls["record"] += 1
+        if waits:
+            self.queue_wait_s += wait_s
+            self.queue_waits += waits
+            if wait_max_s > self.queue_wait_max_s:
+                self.queue_wait_max_s = wait_max_s
+
     @property
     def fault_events(self) -> int:
         return sum(self.faults.values())
@@ -276,6 +317,17 @@ class Telemetry:
         return {k: self.ready_depth_hist[k]
                 for k in sorted(self.ready_depth_hist, key=_bucket_lo)}
 
+    def host_us(self) -> Dict[str, float]:
+        """Host microseconds per call of each dispatch-path phase called."""
+        return {k: round(1e6 * self.host_s[k] / n, 3)
+                for k, n in self.host_calls.items() if n}
+
+    def queue_wait_us(self) -> Dict[str, float]:
+        """Mean and longest host wait from submit to binding, in µs."""
+        mean = self.queue_wait_s / self.queue_waits if self.queue_waits else 0.0
+        return {"mean": round(1e6 * mean, 3),
+                "max": round(1e6 * self.queue_wait_max_s, 3)}
+
     def tenant_percentiles(self) -> Dict[str, Dict[str, float]]:
         """Per-tenant p50/p95/p99 latency (ms, nearest-rank on the sorted
         sample) plus count — the §17 metric that matters at many users.
@@ -331,6 +383,9 @@ class Telemetry:
             "cross_graph_groups": self.cross_graph_groups(),
             "ready_depths": self.ready_depth_histogram(),
             "max_ready_depth": self.max_ready_depth,
+            "host_us": self.host_us(),
+            "host_calls": {k: n for k, n in self.host_calls.items() if n},
+            "queue_wait_us": self.queue_wait_us(),
         }
 
 

@@ -9,6 +9,13 @@ its QKV/FFN GEMM descriptors through the online runtime (shadow dispatch,
 DESIGN.md §10.5): the dynamic logic plans and meters the step's GEMM
 bundle (§6.11 fuse-vs-group included) while the jitted model does the
 math.  Telemetry then reports CD/mode/plan-cache behaviour for the run.
+
+`greedy_decode` writes `jax.profiler` spans, each with the batch size as
+``batch``: ``serve.compile`` around each lower and compile,
+``serve.prefill``, and one step span ``serve.decode`` per decode step
+holding ``serve.submit`` (the runtime's submits), ``serve.dispatch`` (the
+jitted step's enqueue), ``serve.sample`` (the argmax) and
+``serve.flush`` (the runtime's flush or drain).
 """
 from __future__ import annotations
 
@@ -17,6 +24,7 @@ from typing import Any, Callable, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 from repro.models.model import Model
 
@@ -67,18 +75,21 @@ def greedy_decode(
     B = jax.tree.leaves(prompt_batch)[0].shape[0]
     cache = model.init_cache(batch=B, s_max=s_max, dtype=cache_dtype)
     t0 = time.perf_counter()
-    prefill = jax.jit(model.prefill).lower(params, prompt_batch, cache).compile()
+    with TraceAnnotation("serve.compile", batch=B):
+        prefill = jax.jit(model.prefill).lower(params, prompt_batch, cache).compile()
     compile_s = time.perf_counter() - t0
     t0 = time.perf_counter()
-    logits, cache, length = prefill(params, prompt_batch, cache)
-    prefill_logits = jax.block_until_ready(logits)
+    with TraceAnnotation("serve.prefill", batch=B):
+        logits, cache, length = prefill(params, prompt_batch, cache)
+        prefill_logits = jax.block_until_ready(logits)
     prefill_s = time.perf_counter() - t0
     cache_len = jnp.asarray(length, jnp.int32)
     out = []
     tok = jnp.argmax(logits[:, -1], axis=-1)[:, None]
     t0 = time.perf_counter()
-    decode = jax.jit(model.decode_step).lower(
-        params, tok, cache, cache_len).compile()
+    with TraceAnnotation("serve.compile", batch=B):
+        decode = jax.jit(model.decode_step).lower(
+            params, tok, cache, cache_len).compile()
     compile_s += time.perf_counter() - t0
     step_requests = step_bundle = step_graph = None
     if runtime is not None and graph:
@@ -102,24 +113,30 @@ def greedy_decode(
         # step — derive it once, submit it per step
         step_requests = decode_step_requests(runtime.ctrl, model.cfg, B)
     t0 = time.perf_counter()
-    for _ in range(steps):
-        out.append(tok)
-        if step_graph is not None:
-            runtime.submit(step_graph, tenant=tenant)
-        elif step_bundle is not None:
-            runtime.submit(step_bundle, tenant=tenant)
-        elif step_requests is not None:
-            for req in step_requests:
-                runtime.submit(req, tenant=tenant)
-        logits, cache, cache_len = decode(params, tok, cache, cache_len)
-        tok = jnp.argmax(logits[:, -1], axis=-1)[:, None]
-        if runtime is not None:
-            if step_graph is not None:
-                # a graph spans several flushes (each completion wave
-                # releases the next), so drain the whole step
-                runtime.drain()
-            else:
-                runtime.flush(force=True)
+    for k in range(steps):
+        with StepTraceAnnotation("serve.decode", step_num=k, batch=B):
+            out.append(tok)
+            if runtime is not None:
+                with TraceAnnotation("serve.submit", batch=B):
+                    if step_graph is not None:
+                        runtime.submit(step_graph, tenant=tenant)
+                    elif step_bundle is not None:
+                        runtime.submit(step_bundle, tenant=tenant)
+                    else:
+                        for req in step_requests:
+                            runtime.submit(req, tenant=tenant)
+            with TraceAnnotation("serve.dispatch", batch=B):
+                logits, cache, cache_len = decode(params, tok, cache, cache_len)
+            with TraceAnnotation("serve.sample", batch=B):
+                tok = jnp.argmax(logits[:, -1], axis=-1)[:, None]
+            if runtime is not None:
+                with TraceAnnotation("serve.flush", batch=B):
+                    if step_graph is not None:
+                        # a graph spans several flushes (each completion
+                        # wave releases the next), so drain the whole step
+                        runtime.drain()
+                    else:
+                        runtime.flush(force=True)
     tokens = jax.block_until_ready(jnp.concatenate(out, axis=1))
     return Decoded(tokens, prefill_logits, compile_s, prefill_s,
                    time.perf_counter() - t0)

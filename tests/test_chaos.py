@@ -276,9 +276,11 @@ def test_disabled_injection_is_bitwise_identical():
     assert all(ln.fallback is None and ln.penalty_s == 0.0 for ln in la)
     assert armed.telemetry.fault_events == 0
     sp, sa = plain.telemetry.summary(), armed.telemetry.summary()
-    # class_ratios fold in wall-clock achieved times (non-deterministic
-    # across runs); everything modeled must match exactly.
-    sp.pop("class_ratios"), sa.pop("class_ratios")
+    # class_ratios fold in wall-clock achieved times, host_us and
+    # queue_wait_us are host-clock times (non-deterministic across runs);
+    # everything modeled, and every count, must match exactly.
+    for key in ("class_ratios", "host_us", "queue_wait_us"):
+        sp.pop(key), sa.pop(key)
     assert sp == sa
 
 

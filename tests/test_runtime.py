@@ -309,6 +309,65 @@ def test_telemetry_counts_and_histogram():
     assert tele.snapshot() == summary
 
 
+def test_telemetry_host_counters_count_every_submit_and_flush():
+    rt = _runtime(window_s=0.0)
+    for _ in range(3):
+        rt.submit(SMALL, now=0.0)
+    rt.submit([SMALL, OTHER], now=0.0)      # a bundle is one submit
+    rt.flush(now=1.0)
+    rt.flush(now=2.0)                       # nothing ripe: planning only
+    tele = rt.telemetry
+    assert tele.host_calls == {"submit": 4, "plan": 2, "launch": 1, "record": 1}
+    assert all(tele.host_s[k] > 0 for k in tele.host_calls)
+    assert tele.queue_waits == 5            # three ops and the bundle's two
+    assert 0 < tele.queue_wait_max_s <= tele.queue_wait_s
+    summary = tele.summary()
+    assert set(summary["host_us"]) == {"submit", "plan", "launch", "record"}
+    assert summary["host_us"]["plan"] == pytest.approx(
+        1e6 * tele.host_s["plan"] / 2, abs=1e-3)
+    assert summary["host_calls"] == dict(tele.host_calls)
+    wait = summary["queue_wait_us"]
+    assert 0 < wait["mean"] <= wait["max"]
+
+
+def test_virtual_clock_replay_keeps_its_modeled_telemetry():
+    """A replay on a virtual clock, with EDF, admission slicing and
+    deferrals, reads the modeled numbers the runtime gave before its
+    host-clock counters existed: those run beside the timeline, never on
+    it."""
+    from repro.core import GOLibrary
+
+    clock = {"t": 0.0}
+    rt = Runtime(ConcurrencyController(library=GOLibrary()),
+                 RuntimeConfig(policy="edf", slicing=True, flush_budget_s=3e-4),
+                 clock=lambda: clock["t"])
+    rt.set_tenant_slo("chat", TenantSLO("latency", 2.0, 5e-3))
+    descs = {"chat": [GemmDesc(8, 4096, 4096), GemmDesc(8, 11008, 4096)],
+             "batch": [GemmDesc(4096, 8192, 8192), GemmDesc(512, 1024, 4096)]}
+    arrivals = sorted([(t, "chat") for t in poisson_trace(400.0, 0.1, seed=3)]
+                      + [(t, "batch") for t in poisson_trace(60.0, 0.1, seed=4)])
+    tickets = []
+    for i, (t, tenant) in enumerate(arrivals):
+        clock["t"] = t
+        tickets.append(rt.submit(descs[tenant][i % 2], tenant=tenant))
+        rt.flush()
+    clock["t"] = 0.2
+    rt.drain()
+    s = rt.telemetry.summary()
+    assert {k: s[k] for k in ("submitted", "completed", "flushes", "groups", "max_cd",
+                              "modes", "sliced_ops", "deferred_launches")} == {
+        "submitted": 45, "completed": 45, "flushes": 45, "groups": 53, "max_cd": 3,
+        "modes": {"grouped": 5, "single": 48}, "sliced_ops": 2, "deferred_launches": 219}
+    assert s["mean_cd"] == 1.113 and s["plan_cache_hit_rate"] == 0.6721
+    assert s["modeled_busy_time_us"] == pytest.approx(9214.96, abs=1e-6)
+    assert s["tenants"] == {
+        "batch": {"n": 2, "p50_ms": 132.9104, "p95_ms": 137.2162, "p99_ms": 137.2162},
+        "chat": {"n": 43, "p50_ms": 3.1244, "p95_ms": 9.6212, "p99_ms": 103.059}}
+    assert rt.device_free_t == pytest.approx(0.20340411054637242, rel=1e-12)
+    assert sum(t.latency_s for t in tickets) == pytest.approx(0.6018931006914715, rel=1e-12)
+    assert rt.telemetry.host_calls["submit"] == 45
+
+
 def test_prewarm_tunes_and_seeds_plan_cache():
     rt = _runtime(window_s=0.0)
     fresh = rt.prewarm([SMALL, SMALL, OTHER])
