@@ -1,9 +1,11 @@
 """Attention variants: GQA (opt. QKV-bias / qk-norm / sliding window) and
 DeepSeek-V2 MLA (latent-compressed KV, absorbed decode path).
 
-Caches are fixed-capacity ring-less buffers (S_max slots); `length` is the
-number of valid tokens.  Decode (T==1) uses a GEMV path against the cache;
-MLA decode uses the *absorbed* formulation so the per-step cost scales with
+Caches are fixed-capacity ring-less buffers (S_max slots), stacked over the
+layers and updated in place: each layer writes its new tokens into its own
+row and reads that row; `length` is the number of valid tokens.  Decode
+(T==1) runs the decode-attention kernel against the stacked cache; MLA
+decode uses the *absorbed* formulation so the per-step cost scales with
 the latent rank, not the expanded heads — mandatory at 32k/500k contexts.
 """
 from __future__ import annotations
@@ -14,32 +16,58 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig
+from repro.kernels.decode_attention import decode_attention
 from repro.kernels.flash_attention import flash_attention
-from repro.models.common import apply_rope, rms_norm
+from repro.models.common import apply_rope, head_rms_norm, rms_norm
 from repro.models.spec import Spec
 
 NEG_INF = -1e30
 
 
-def _pin_cache(x, mesh):
-    """Pin a per-layer cache slice to (batch over DP, model-replicated or
-    head-sharded) — prevents GSPMD from bouncing the multi-GB cache across
-    the model axis every layer (§Perf decode iteration 2)."""
-    if mesh is None:
-        return x
+def batch_axes(mesh, batch: int):
+    """The data-parallel axes of ``mesh`` ('pod', 'data') over which
+    ``batch`` rows split evenly, dropped from the last until they do;
+    None where none is left."""
     import numpy as np
-    from jax.sharding import NamedSharding, PartitionSpec as P
 
     dp = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
-    B = x.shape[0]
-    while dp and B % int(np.prod([mesh.shape[a] for a in dp])) != 0:
+    while dp and batch % int(np.prod([mesh.shape[a] for a in dp])) != 0:
         dp = dp[:-1]
-    spec = [dp if dp else None] + [None] * (x.ndim - 1)
-    if x.ndim == 4 and mesh.shape.get("model", 1) > 1             and x.shape[2] % mesh.shape["model"] == 0:
+    return dp or None
+
+
+def pin_cache(x, mesh):
+    """Pin a stacked cache buffer, (L, B, Hkv, hd, S) for K/V or (L, B, S, r)
+    for a latent, to batch over DP and KV heads over 'model' where they
+    divide (`Model.cache_pspecs`' layout) — prevents GSPMD from bouncing
+    the multi-GB cache across the model axis every layer (§Perf decode
+    iteration 2)."""
+    if mesh is None:
+        return x
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    spec = [None, batch_axes(mesh, x.shape[1])] + [None] * (x.ndim - 2)
+    if x.ndim == 5 and mesh.shape.get("model", 1) > 1 \
+            and x.shape[2] % mesh.shape["model"] == 0:
         spec[2] = "model"  # kv heads
     return jax.lax.with_sharding_constraint(
         x, NamedSharding(mesh, P(*spec))
     )
+
+
+def _write_token(buf, new, layer, cache_len, axis):
+    """``new`` written into the stacked ``buf`` at row ``layer``, from
+    position ``cache_len`` along ``axis`` of one layer: one
+    dynamic_update_slice, which XLA does in place on a carried buffer."""
+    idx = [jnp.zeros((), jnp.int32)] * buf.ndim
+    idx[0] = jnp.asarray(layer, jnp.int32)
+    idx[axis + 1] = jnp.asarray(cache_len, jnp.int32)
+    return jax.lax.dynamic_update_slice(buf, new.astype(buf.dtype)[None], idx)
+
+
+def _layer(buf, layer):
+    """Row ``layer`` of a stacked cache buffer, for an operand that reads it."""
+    return jax.lax.dynamic_index_in_dim(buf, layer, axis=0, keepdims=False)
 
 
 # =========================================================== GQA attention
@@ -63,14 +91,19 @@ def gqa_specs(cfg: ArchConfig) -> dict:
 
 
 class KVCache(NamedTuple):
-    k: jax.Array  # (B, S_max, Hkv, hd)
-    v: jax.Array
+    k: jax.Array  # (B, Hkv, hd, S): positions minor, the layout the decode
+    v: jax.Array  # kernel reads (K transposed for q·K, V for p·Vᵀ)
     # length is tracked by the caller (shared across layers)
 
 
 def init_kv_cache(cfg: ArchConfig, batch: int, s_max: int, dtype) -> KVCache:
+    """A zero KV cache for ``s_max`` positions, rounded up to a multiple of
+    128: whole lane tiles, in which the TPU keeps the buffer row-major, as
+    the decode kernel reads it (at 769 positions, with 128-wide heads, it
+    picks another layout, and XLA would relayout the whole cache every
+    step)."""
     hd = cfg.resolved_head_dim
-    shape = (batch, s_max, cfg.n_kv_heads, hd)
+    shape = (batch, cfg.n_kv_heads, hd, -(-s_max // 128) * 128)
     return KVCache(jnp.zeros(shape, dtype), jnp.zeros(shape, dtype))
 
 
@@ -80,10 +113,15 @@ def gqa_apply(
     cfg: ArchConfig,
     positions: jax.Array,       # (B, T) absolute positions
     window: int = 0,
-    cache: Optional[KVCache] = None,
+    cache: Optional[KVCache] = None,   # stacked: (L, B, Hkv, hd, S)
     cache_len: Optional[jax.Array] = None,  # scalar current length
+    layer=None,                 # this layer's row of the stacked cache
+    global_layer=None,          # traced bool: attend globally, not in window
     mesh=None,
 ):
+    """GQA attention of one layer.  With a cache, the layer's new K/V are
+    written into its row of the stacked buffer and the attention reads
+    that row; the buffer comes back whole, updated in place."""
     B, T, D = x.shape
     hd = cfg.resolved_head_dim
     hq, hkv = cfg.n_heads, cfg.n_kv_heads
@@ -97,100 +135,89 @@ def gqa_apply(
     k = k.reshape(B, T, hkv, hd)
     v = v.reshape(B, T, hkv, hd)
     if cfg.qk_norm:
-        q = rms_norm(p["q_norm"], q, cfg.norm_eps)
-        k = rms_norm(p["k_norm"], k, cfg.norm_eps)
+        q = head_rms_norm(p["q_norm"], q, cfg.norm_eps)
+        k = head_rms_norm(p["k_norm"], k, cfg.norm_eps)
     q = apply_rope(q, positions, cfg.rope_theta)
     k = apply_rope(k, positions, cfg.rope_theta)
 
-    if cache is None:
-        out = _flash_heads(
-            q.transpose(0, 2, 1, 3),
-            k.transpose(0, 2, 1, 3),
-            v.transpose(0, 2, 1, 3),
-            mesh,
-            causal=True,
-            window=window,
-        ).transpose(0, 2, 1, 3)
-        new_cache = None
+    new_cache = None
+    if cache is not None and T == 1:
+        # Decode: the kernel writes the token's K/V into the layer's row and
+        # attends over it; the window is a scalar to it, so gemma3's
+        # local/global choice needs no cond around the stacked buffer.
+        if global_layer is not None:
+            window = jnp.where(global_layer, 0, window)
+        out, kc, vc = _decode_heads(
+            (q * hd ** -0.5).reshape(B, hq * hd), k.reshape(B, hkv * hd),
+            v.reshape(B, hkv * hd), cache, layer, cache_len, window, mesh)
+        new_cache = KVCache(pin_cache(kc, mesh), pin_cache(vc, mesh))
     else:
-        # Reshard the (tiny) new-token K/V to the cache's batch-only layout
-        # BEFORE the write: otherwise GSPMD propagates the TP sharding of
-        # the projection into the multi-GB cache and re-gathers it every
-        # layer (§Perf decode iteration 4 — the winning move).
-        k = _pin_batch_only(k.astype(cache.k.dtype), mesh)
-        v = _pin_batch_only(v.astype(cache.v.dtype), mesh)
-        with jax.named_scope("kv_write"):
-            kc = jax.lax.dynamic_update_slice_in_dim(cache.k, k, cache_len, axis=1)
-            vc = jax.lax.dynamic_update_slice_in_dim(cache.v, v, cache_len, axis=1)
-        kc, vc = _pin_cache(kc, mesh), _pin_cache(vc, mesh)
-        new_cache = KVCache(kc, vc)
-        if T > 1:
-            # Prefill: flash attention against the written cache buffer —
-            # the dense GEMV path would materialize O(T·S) scores
-            # (§Perf prefill iteration 1).
-            out = _flash_heads(
+        if cache is not None:
+            # Prefill, from position 0.  Reshard the prompt's K/V to the
+            # cache's batch-only layout BEFORE the write: otherwise GSPMD
+            # propagates the TP sharding of the projection into the
+            # multi-GB cache and re-gathers it every layer (§Perf decode
+            # iteration 4 — the winning move).
+            with jax.named_scope("kv_write"):
+                kc = _write_token(cache.k, _pin_batch_only(k.transpose(0, 2, 3, 1), mesh),
+                                  layer, cache_len, axis=3)
+                vc = _write_token(cache.v, _pin_batch_only(v.transpose(0, 2, 3, 1), mesh),
+                                  layer, cache_len, axis=3)
+            new_cache = KVCache(pin_cache(kc, mesh), pin_cache(vc, mesh))
+
+        def attend(w):
+            # Training, or prefill: its prompt's own K/V are all the cache
+            # holds, so flash attention over them; the dense decode path
+            # would materialize O(T·S) scores (§Perf prefill iteration 1).
+            return _flash_heads(
                 q.transpose(0, 2, 1, 3),
-                kc.transpose(0, 2, 1, 3),
-                vc.transpose(0, 2, 1, 3),
+                k.transpose(0, 2, 1, 3),
+                v.transpose(0, 2, 1, 3),
                 mesh,
                 causal=True,
-                window=window,
-                q_offset=0,  # prefill starts at position 0
+                window=w,
             ).transpose(0, 2, 1, 3)
+
+        if global_layer is None:
+            out = attend(window)
         else:
-            out = _attend_cache(
-                q, kc, vc, q_pos=positions, length=cache_len + T,
-                window=window, mesh=mesh,
-            )
+            # the window must be static for the flash kernel: a cond over
+            # the two static variants (gemma3's local:global pattern)
+            out = jax.lax.cond(global_layer, lambda: attend(0),
+                               lambda: attend(window))
     y = out.reshape(B, T, hq * hd) @ p["wo"]
     return y, new_cache
 
 
-def _attend_cache(q, kc, vc, *, q_pos, length, window, mesh=None):
-    """Decode/verify attention against a fixed-size cache (GEMV path).
+def _decode_heads(q, k_new, v_new, cache: KVCache, layer, pos, window, mesh):
+    """`decode_attention` on (B, H·hd) rows and the stacked cache, with the
+    heads sharded over 'model' as the cache holds them: one shard_map
+    program per head shard, since the partitioner cannot split the
+    kernel's custom call (see `_flash_heads`).  Returns (o, k, v)."""
+    args = (q, k_new, v_new, cache.k, cache.v, layer, pos,
+            jnp.asarray(window, jnp.int32))
+    if mesh is None or mesh.shape.get("model", 1) <= 1:
+        return decode_attention(*args)
+    from jax.sharding import PartitionSpec as P
 
-    q (B,T,Hq,hd); kc/vc (B,S,Hkv,hd); q_pos (B,T); length = valid tokens.
-    The cache stays in its storage dtype (bf16) with f32 *accumulation*
-    only, and the score einsum is pinned batch-sharded: replicating the
-    tiny GEMV over the model axis is far cheaper than GSPMD's alternative
-    of head-sharding + re-gathering the multi-GB cache every layer
-    (§Perf decode iterations 2–3).
-    """
-    B, T, Hq, hd = q.shape
-    S, Hkv = kc.shape[1], kc.shape[2]
-    rep = Hq // Hkv
-    qf = (q * (hd ** -0.5)).astype(kc.dtype)
-    qf = qf.reshape(B, T, Hkv, rep, hd)
-    s = jnp.einsum(
-        "bthrd,bshd->bthrs", qf, kc, preferred_element_type=jnp.float32
-    )
-    s = _pin_batch_only(s, mesh)
-    kpos = jnp.arange(S)
-    mask = kpos[None, None, :] < length
-    mask &= q_pos[..., None] >= kpos[None, None, :]
-    if window:
-        mask &= q_pos[..., None] - kpos[None, None, :] < window
-    s = jnp.where(mask[:, :, None, None, :], s, NEG_INF)
-    pattn = jax.nn.softmax(s, axis=-1).astype(vc.dtype)
-    out = jnp.einsum(
-        "bthrs,bshd->bthrd", pattn, vc, preferred_element_type=jnp.float32
-    )
-    out = _pin_batch_only(out, mesh)
-    return out.reshape(B, T, Hq, hd).astype(q.dtype)
+    if cache.k.shape[2] % mesh.shape["model"]:
+        return decode_attention(*args)
+    dp = batch_axes(mesh, q.shape[0])
+    row, stack = P(dp, "model"), P(None, dp, "model", None, None)
+    return jax.shard_map(
+        decode_attention, mesh=mesh,
+        in_specs=(row, row, row, stack, stack, P(), P(), P()),
+        out_specs=(row, stack, stack), check_vma=False,
+    )(*args)
 
 
 def _pin_batch_only(x, mesh):
     if mesh is None:
         return x
-    import numpy as np
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    dp = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
-    B = x.shape[0]
-    while dp and B % int(np.prod([mesh.shape[a] for a in dp])) != 0:
-        dp = dp[:-1]
     return jax.lax.with_sharding_constraint(
-        x, NamedSharding(mesh, P(dp if dp else None,
+        x, NamedSharding(mesh, P(batch_axes(mesh, x.shape[0]),
                                  *([None] * (x.ndim - 1))))
     )
 
@@ -204,17 +231,12 @@ def _flash_heads(q, k, v, mesh, **kw):
     shard keeps the same q-per-kv ratio, so GQA maps stay local."""
     if mesh is None or mesh.shape.get("model", 1) <= 1:
         return flash_attention(q, k, v, **kw)
-    import numpy as np
     from jax.sharding import PartitionSpec as P
 
     msize = mesh.shape["model"]
     if q.shape[1] % msize or k.shape[1] % msize:
         return flash_attention(q, k, v, **kw)
-    dp = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
-    B = q.shape[0]
-    while dp and B % int(np.prod([mesh.shape[a] for a in dp])) != 0:
-        dp = dp[:-1]
-    spec = P(dp if dp else None, "model", None, None)
+    spec = P(batch_axes(mesh, q.shape[0]), "model", None, None)
     return jax.shard_map(
         lambda q, k, v: flash_attention(q, k, v, **kw), mesh=mesh,
         in_specs=(spec, spec, spec), out_specs=spec, check_vma=False,
@@ -274,8 +296,9 @@ def mla_apply(
     x: jax.Array,
     cfg: ArchConfig,
     positions: jax.Array,
-    cache: Optional[MLACache] = None,
+    cache: Optional[MLACache] = None,  # stacked: (L, B, S_max, r | dr)
     cache_len: Optional[jax.Array] = None,
+    layer=None,                        # this layer's row of the stacked cache
     mesh=None,
 ):
     B, T, D = x.shape
@@ -293,11 +316,22 @@ def mla_apply(
         ckv_full[..., r:][:, :, None, :], positions, cfg.rope_theta
     )[:, :, 0, :]  # single shared rope head (B, T, dr)
 
+    new_cache = None
+    if cache is not None:
+        # New-token latents resharded to the cache layout before the write
+        # (see GQA).
+        ckv_w = _pin_batch_only(ckv, mesh)
+        krope_w = _pin_batch_only(krope, mesh)
+        with jax.named_scope("kv_write"):
+            ckv_s = _write_token(cache.ckv, ckv_w, layer, cache_len, axis=1)
+            krope_s = _write_token(cache.krope, krope_w, layer, cache_len, axis=1)
+        new_cache = MLACache(pin_cache(ckv_s, mesh), pin_cache(krope_s, mesh))
+
     if cache is None or T > 1:
         # Training / prefill: expand latents to per-head K/V (standard
-        # path, flash kernel).  Prefill (cache given, cache_len==0) also
-        # writes the latent cache — the absorbed dense path would
-        # materialize O(T·S) scores (§Perf prefill iteration 1).
+        # path, flash kernel); prefill has written the latent cache above —
+        # the absorbed dense path would materialize O(T·S) scores (§Perf
+        # prefill iteration 1).
         k_nope = (ckv @ p["wuk"]).reshape(B, T, h, dn)
         v = (ckv @ p["wuv"]).reshape(B, T, h, dv)
         k = jnp.concatenate(
@@ -313,34 +347,11 @@ def mla_apply(
             causal=True,
             scale=scale,
         ).transpose(0, 2, 1, 3)
-        if cache is not None:
-            ckv_w = _pin_batch_only(ckv.astype(cache.ckv.dtype), mesh)
-            krope_w = _pin_batch_only(krope.astype(cache.krope.dtype), mesh)
-            with jax.named_scope("kv_write"):
-                new_cache = MLACache(
-                    jax.lax.dynamic_update_slice_in_dim(
-                        cache.ckv, ckv_w, cache_len, axis=1
-                    ),
-                    jax.lax.dynamic_update_slice_in_dim(
-                        cache.krope, krope_w, cache_len, axis=1
-                    ),
-                )
-        else:
-            new_cache = None
     else:
-        # Absorbed decode: score/value directly in latent space.  New-token
-        # latents resharded to the cache layout before the write (see GQA).
-        ckv_w = _pin_batch_only(ckv.astype(cache.ckv.dtype), mesh)
-        krope_w = _pin_batch_only(krope.astype(cache.krope.dtype), mesh)
-        with jax.named_scope("kv_write"):
-            ckv_c = jax.lax.dynamic_update_slice_in_dim(
-                cache.ckv, ckv_w, cache_len, axis=1
-            )
-            krope_c = jax.lax.dynamic_update_slice_in_dim(
-                cache.krope, krope_w, cache_len, axis=1
-            )
-        ckv_c, krope_c = _pin_cache(ckv_c, mesh), _pin_cache(krope_c, mesh)
-        new_cache = MLACache(ckv_c, krope_c)
+        # Absorbed decode: score/value directly in latent space, reading
+        # this layer's row of the written cache.
+        ckv_c = _layer(new_cache.ckv, layer)
+        krope_c = _layer(new_cache.krope, layer)
         wuk = p["wuk"].reshape(r, h, dn)
         # q absorbed into latent space: (B,T,h,r)
         q_lat = jnp.einsum("bthn,rhn->bthr", q_nope.astype(jnp.float32),

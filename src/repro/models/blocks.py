@@ -61,8 +61,10 @@ def attn_block_apply(
     *,
     moe: bool,
     window: int = 0,
+    global_layer=None,
     cache=None,
     cache_len=None,
+    layer=None,
     mesh=None,
     moe_mode: str = "auto",
     moe_capacity_factor: float = 1.25,
@@ -72,12 +74,13 @@ def attn_block_apply(
         if cfg.attn_type == "mla":
             a, new_cache = mla_apply(
                 p["attn"], h, cfg, positions, cache=cache, cache_len=cache_len,
-                mesh=mesh,
+                layer=layer, mesh=mesh,
             )
         else:
             a, new_cache = gqa_apply(
                 p["attn"], h, cfg, positions, window=window,
-                cache=cache, cache_len=cache_len, mesh=mesh,
+                cache=cache, cache_len=cache_len, layer=layer,
+                global_layer=global_layer, mesh=mesh,
             )
     x = x + a
     h = rms_norm(p["mlp_norm"], x, cfg.norm_eps)
@@ -140,11 +143,13 @@ def zamba_layer_apply(
     else:
         def with_shared(args):
             x, kv = args
+            # this layer's own KV cache, as a stack of one
             y, new_kv, _ = attn_block_apply(
                 shared_p, x, cfg, positions, moe=False,
-                cache=kv, cache_len=cache_len, mesh=mesh,
+                cache=jax.tree.map(lambda a: a[None], kv),
+                cache_len=cache_len, layer=0, mesh=mesh,
             )
-            return y, new_kv
+            return y, jax.tree.map(lambda a: a[0], new_kv)
 
         x2, new_kv = jax.lax.cond(
             apply_shared, with_shared, lambda a: a, (x, cache["kv"])

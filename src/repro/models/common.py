@@ -29,19 +29,32 @@ def rope_freqs(head_dim: int, theta: float):
 
 
 def apply_rope(x, positions, theta: float = 10_000.0):
-    """x: (..., T, H, D) rotated pairwise; positions: (..., T) or (T,)."""
-    D = x.shape[-1]
+    """x: (..., T, H, D) rotated pairwise; positions: (..., T) or (T,).
+
+    The products by cos and sin run on the rows of all heads, (..., T, H·D),
+    as the projections make them: per head, XLA lays the step's q and k
+    out heads-major and relays out the projection weights to match, a
+    copy of each weight every layer of every decode step."""
+    *lead, H, D = x.shape
     freqs = rope_freqs(D, theta)  # (D/2,)
     ang = positions[..., None].astype(jnp.float32) * freqs  # (..., T, D/2)
-    cos, sin = jnp.cos(ang), jnp.sin(ang)
-    cos = cos[..., None, :]  # (..., T, 1, D/2) broadcast over heads
-    sin = sin[..., None, :]
-    x1, x2 = x[..., : D // 2], x[..., D // 2 :]
-    xf1, xf2 = x1.astype(jnp.float32), x2.astype(jnp.float32)
-    out = jnp.concatenate(
-        [xf1 * cos - xf2 * sin, xf2 * cos + xf1 * sin], axis=-1
-    )
-    return out.astype(x.dtype)
+    cos = jnp.tile(jnp.cos(ang), 2 * H)  # (..., T, H·D)
+    sin = jnp.tile(jnp.sin(ang), 2 * H)
+    xf = x.reshape(*lead, H * D).astype(jnp.float32)
+    xh = xf.reshape(x.shape)
+    rot = jnp.concatenate([-xh[..., D // 2:], xh[..., : D // 2]], axis=-1)
+    return (xf * cos + rot.reshape(xf.shape) * sin).astype(x.dtype).reshape(x.shape)
+
+
+def head_rms_norm(w, x, eps: float = 1e-5):
+    """`rms_norm` of each head of x (..., H, D), with the scaling on the
+    rows of all heads (..., H·D), for the layout reason of `apply_rope`."""
+    *lead, H, D = x.shape
+    xf = x.reshape(*lead, H * D).astype(jnp.float32)
+    var = jnp.mean(jnp.square(xf).reshape(*lead, H, D), axis=-1)
+    s = jnp.repeat(jax.lax.rsqrt(var + eps), D, axis=-1)
+    out = xf * s * jnp.tile(w.astype(jnp.float32), H)
+    return out.astype(x.dtype).reshape(x.shape)
 
 
 # ------------------------------------------------------------------- mlp

@@ -18,7 +18,9 @@ import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig
 from repro.models import blocks as B
-from repro.models.attention import init_kv_cache, init_mla_cache
+from repro.models.attention import (
+    batch_axes, init_kv_cache, init_mla_cache, pin_cache,
+)
 from repro.models.common import (
     cross_entropy,
     embed_apply,
@@ -128,53 +130,29 @@ class Model:
 
     def _scan_attn(self, stack, x, positions, *, moe, cache, cache_len,
                    layer_offset):
+        """The layer stack ``stack`` scanned over ``x``.  The stacked cache
+        rides in the carry, not in the scanned inputs and outputs: each
+        layer writes its new tokens into its own row (``i - layer_offset``)
+        and its attention reads that row, so a step moves no layer's cache
+        but through the attention, and the buffer is updated in place."""
         cfg = self.cfg
 
         @jax.named_scope("block")
-        def body(carry, inp):
-            x, i = carry
-            p, c = inp
+        def body(carry, p):
+            x, i, c = carry
+            global_layer = None
             if cfg.sliding_window and cfg.local_global_ratio:
-                # window must be static for the kernel: cond over the two
-                # static variants (gemma3's 5 local : 1 global pattern).
+                # gemma3: every (r+1)-th layer global, the others windowed
                 r = cfg.local_global_ratio
-                is_global = (i % (r + 1)) == r
-
-                def glob(args):
-                    x, p, c = args
-                    return B.attn_block_apply(
-                        p, x, cfg, positions, moe=moe, window=0,
-                        cache=c, cache_len=cache_len, mesh=self.mesh,
-                        moe_mode=self.moe_mode,
-                        moe_capacity_factor=self.moe_capacity_factor,
-                    )
-
-                def local(args):
-                    x, p, c = args
-                    return B.attn_block_apply(
-                        p, x, cfg, positions, moe=moe,
-                        window=cfg.sliding_window,
-                        cache=c, cache_len=cache_len, mesh=self.mesh,
-                        moe_mode=self.moe_mode,
-                        moe_capacity_factor=self.moe_capacity_factor,
-                    )
-
-                y, new_c, aux = jax.lax.cond(is_global, glob, local, (x, p, c))
-            elif cfg.sliding_window:
-                y, new_c, aux = B.attn_block_apply(
-                    p, x, cfg, positions, moe=moe, window=cfg.sliding_window,
-                    cache=c, cache_len=cache_len, mesh=self.mesh,
-                    moe_mode=self.moe_mode,
-                    moe_capacity_factor=self.moe_capacity_factor,
-                )
-            else:
-                y, new_c, aux = B.attn_block_apply(
-                    p, x, cfg, positions, moe=moe, window=0,
-                    cache=c, cache_len=cache_len, mesh=self.mesh,
-                    moe_mode=self.moe_mode,
-                    moe_capacity_factor=self.moe_capacity_factor,
-                )
-            return (y, i + 1), (new_c, aux)
+                global_layer = (i % (r + 1)) == r
+            y, c, aux = B.attn_block_apply(
+                p, x, cfg, positions, moe=moe, window=cfg.sliding_window,
+                global_layer=global_layer, cache=c, cache_len=cache_len,
+                layer=i - layer_offset, mesh=self.mesh,
+                moe_mode=self.moe_mode,
+                moe_capacity_factor=self.moe_capacity_factor,
+            )
+            return (y, i + 1, c), aux
 
         if self.remat == "full":
             body = jax.checkpoint(body)
@@ -185,11 +163,14 @@ class Model:
                 body,
                 policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
             )
+        # the same sharding in and out of the step, so the next step's
+        # compiled program takes the cache this one returns
+        pin = functools.partial(jax.tree.map, lambda c: pin_cache(c, self.mesh))
         with jax.named_scope("layers"):
-            (x, _), (new_cache, auxs) = jax.lax.scan(
-                body, (x, layer_offset), (stack, cache)
+            (x, _, new_cache), auxs = jax.lax.scan(
+                body, (x, layer_offset, pin(cache)), stack
             )
-        return x, new_cache, auxs.sum()
+        return x, pin(new_cache), auxs.sum()
 
     def _scan_zamba(self, params, x, positions, cache, cache_len):
         cfg = self.cfg
@@ -250,6 +231,21 @@ class Model:
 
     # ------------------------------------------------------------ serving
     def init_cache(self, batch: int, s_max: int, dtype=jnp.bfloat16):
+        """A zero cache for ``batch`` sequences of up to ``s_max`` positions.
+        On the model's mesh it is made in place, in `cache_pspecs`' layout:
+        made on one device, it would be copied to every device of the mesh
+        on the way into the prefill."""
+        make = functools.partial(self._zero_cache, batch, s_max, dtype)
+        if self.mesh is None:
+            return make()
+        from jax.sharding import NamedSharding, PartitionSpec
+
+        pspecs = self.cache_pspecs(self.mesh, jax.eval_shape(make))
+        return jax.jit(make, out_shardings=jax.tree.map(
+            lambda p: NamedSharding(self.mesh, p), pspecs,
+            is_leaf=lambda x: isinstance(x, PartitionSpec)))()
+
+    def _zero_cache(self, batch: int, s_max: int, dtype):
         cfg = self.cfg
         fam = cfg.family
 
@@ -343,25 +339,18 @@ class Model:
         from jax.sharding import PartitionSpec as P
 
         cfg = self.cfg
-        dp_all = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
         msize = mesh.shape.get("model", 1)
-
-        def dp_for(b):
-            dp = dp_all
-            import numpy as _np
-            while dp and b % int(_np.prod([mesh.shape[a] for a in dp])) != 0:
-                dp = dp[:-1]
-            return dp if dp else None
+        dp_for = functools.partial(batch_axes, mesh)
 
         def m_for(d):
             return "model" if (msize > 1 and d % msize == 0) else None
 
         def kv_spec(tree, lead):
-            # KVCache (L,B,S,H,hd) | MLACache ckv (L,B,S,r), krope (L,B,S,dr)
+            # KVCache (L,B,H,hd,S) | MLACache ckv (L,B,S,r), krope (L,B,S,dr)
             def one(x):
                 sh = x.shape
                 if len(sh) == 5:   # k/v
-                    return P(*lead, dp_for(sh[1]), None, m_for(sh[3]), None)
+                    return P(*lead, dp_for(sh[1]), m_for(sh[2]), None, None)
                 return P(*lead, dp_for(sh[1]), None, None)
             return jax.tree.map(one, tree)
 

@@ -51,6 +51,14 @@ def make_serve_fns(model: Model) -> Tuple[Callable, Callable]:
     return prefill, decode_step
 
 
+def serving_jit(fn: Callable) -> Callable:
+    """``fn`` (`Model.prefill` or `Model.decode_step`, the cache their
+    third argument) jitted with the cache donated: the step writes its
+    tokens into the caller's buffer in place, and the caller's cache is
+    consumed.  Compile each program of `greedy_decode` through this."""
+    return jax.jit(fn, donate_argnums=2)
+
+
 def greedy_decode(
     model: Model, params, prompt_batch, *, s_max: int, steps: int,
     cache_dtype=jnp.float32, runtime: Optional[Any] = None,
@@ -76,7 +84,8 @@ def greedy_decode(
     cache = model.init_cache(batch=B, s_max=s_max, dtype=cache_dtype)
     t0 = time.perf_counter()
     with TraceAnnotation("serve.compile", batch=B):
-        prefill = jax.jit(model.prefill).lower(params, prompt_batch, cache).compile()
+        prefill = serving_jit(model.prefill).lower(
+            params, prompt_batch, cache).compile()
     compile_s = time.perf_counter() - t0
     t0 = time.perf_counter()
     with TraceAnnotation("serve.prefill", batch=B):
@@ -88,7 +97,7 @@ def greedy_decode(
     tok = jnp.argmax(logits[:, -1], axis=-1)[:, None]
     t0 = time.perf_counter()
     with TraceAnnotation("serve.compile", batch=B):
-        decode = jax.jit(model.decode_step).lower(
+        decode = serving_jit(model.decode_step).lower(
             params, tok, cache, cache_len).compile()
     compile_s += time.perf_counter() - t0
     step_requests = step_bundle = step_graph = None

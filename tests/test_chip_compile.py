@@ -14,11 +14,13 @@ import os
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.core.tuner import SCAN_TILES
 from repro.kernels.dispatch import force_pallas
+from repro.kernels.decode_attention import decode_attention
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.gemm import TileConfig, gemm
 from repro.kernels.grouped_gemm import grouped_gemm, ragged_gemm
@@ -127,6 +129,66 @@ def test_flash_decode_compiles(one_chip):
                                              interpret=False),
              one_chip, ((8, 32, 8, 80), BF16), ((8, 32, S, 80), BF16),
              ((8, 32, S, 80), BF16))
+
+
+# The decode kernel against a stacked, positions-minor cache at the cells'
+# widths: stablelm-3b (MHA, hd 80) and one chip's share of qwen3-14b
+# (GQA 5:1, hd 128), cache capacity 769 rounded up to 896; and at the long
+# capacities the repo declares, where the positions take several grid
+# steps: stablelm-3b at 8k, qwen3-14b at decode_32k, and zamba2-1.2b's
+# shared attention (hd 64) at long_500k.
+@pytest.mark.parametrize("L,B,hq,hkv,hd,S", [
+    (32, 16, 32, 32, 80, 896), (40, 32, 10, 2, 128, 896), (8, 4, 16, 8, 128, 896),
+    (2, 16, 32, 32, 80, 8192), (1, 8, 40, 8, 128, 32768),
+    (1, 1, 32, 32, 64, 524288)],
+    ids=["stablelm-hd80", "qwen3-tp4-hd128", "gqa-2to1", "stablelm-8k",
+         "qwen3-32k", "zamba2-512k"])
+def test_decode_attention_compiles(one_chip, L, B, hq, hkv, hd, S):
+    cache = ((L, B, hkv, hd, S), BF16)
+    new = ((B, hkv * hd), BF16)
+    _compile(lambda q, kn, vn, k, v, i, n, w: decode_attention(
+                 q, kn, vn, k, v, i, n, w, interpret=False),
+             one_chip, ((B, hq * hd), jnp.float32), new, new, cache, cache,
+             ((), jnp.int32), ((), jnp.int32), ((), jnp.int32))
+
+
+def test_decode_step_updates_the_cache_in_place(one_chip):
+    """stablelm-3b's decode step at its widths (two layers), compiled as
+    `greedy_decode` compiles it: the cache is donated and aliased to the
+    output, and the program holds no buffer for it of its own — no layer
+    of the cache is sliced out, relaid out or written back whole."""
+    import dataclasses
+
+    from jax.sharding import Mesh, NamedSharding
+
+    from repro.configs import get_arch
+    from repro.dist.sharding import named, params_pspecs
+    from repro.models import build_model
+    from repro.train.serve_loop import serving_jit
+
+    mesh = Mesh(np.asarray(list(one_chip.device_set)).reshape(1, 1), ("data", "model"))
+    model = build_model(dataclasses.replace(get_arch("stablelm-3b"), n_layers=2),
+                        mesh=mesh)
+
+    def sds(tree, shardings):
+        return jax.tree.map(lambda a, sh: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=sh), tree, shardings)
+
+    params = jax.eval_shape(lambda k: model.init(k, BF16), jax.random.PRNGKey(0))
+    params = sds(params, named(mesh, params_pspecs(model, mesh)))
+    cache = jax.eval_shape(lambda: model.init_cache(batch=16, s_max=769, dtype=BF16))
+    cache = sds(cache, named(mesh, model.cache_pspecs(mesh, cache)))
+    rep = NamedSharding(mesh, jax.sharding.PartitionSpec())
+    with force_pallas(True):
+        compiled = serving_jit(model.decode_step).lower(
+            params, jax.ShapeDtypeStruct((16, 1), jnp.int32, sharding=rep), cache,
+            jax.ShapeDtypeStruct((), jnp.int32, sharding=rep)).compile()
+    mem = compiled.memory_analysis()
+    cache_bytes = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(cache))
+    layer_bytes = cache_bytes // (2 * 2)
+    assert mem.alias_size_in_bytes == cache_bytes
+    assert mem.temp_size_in_bytes < layer_bytes // 8
+    assert "goldyloc_decode_attn" in compiled.as_text()
 
 
 @pytest.mark.parametrize("chunk", [t.bm for t in SCAN_TILES])
