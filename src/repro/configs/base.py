@@ -11,7 +11,7 @@ from __future__ import annotations
 import dataclasses
 import math
 from dataclasses import dataclass, field, replace
-from typing import Dict, Optional
+from typing import Dict, Optional, Tuple
 
 _REGISTRY: Dict[str, "ArchConfig"] = {}
 
@@ -35,6 +35,7 @@ class ArchConfig:
     sliding_window: int = 0          # 0 -> full attention
     local_global_ratio: int = 0      # gemma3: N local layers per global layer
     rope_theta: float = 10_000.0
+    attn_scale: float = 0.0          # 0 -> head_dim ** -0.5
 
     # --- MLA (DeepSeek-V2) --------------------------------------------------
     kv_lora_rank: int = 0
@@ -56,7 +57,14 @@ class ArchConfig:
     ssm_head_dim: int = 64
     ssm_expand: int = 2
     ssm_conv: int = 4
-    attn_every: int = 0              # zamba2: shared attn block period
+    ssm_ngroups: int = 1             # B/C groups, each shared by H/G heads
+    ssm_dt_min: float = 0.0          # dt clamped from below (time_step_min)
+
+    # --- zamba2 shared blocks -------------------------------------------------
+    hybrid_layer_ids: Tuple[int, ...] = ()  # layers a shared block feeds
+    num_mem_blocks: int = 1          # shared blocks, used in turn
+    adapter_rank: int = 0            # per-use LoRA on the shared MLP's gate/up
+    concat_embed: bool = False       # shared block reads [stream ‖ embedding]
 
     # --- xLSTM ---------------------------------------------------------------
     slstm_every: int = 0             # 1-in-k layers are sLSTM, rest mLSTM
@@ -71,6 +79,20 @@ class ArchConfig:
     @property
     def resolved_head_dim(self) -> int:
         return self.head_dim or (self.d_model // self.n_heads)
+
+    @property
+    def resolved_attn_scale(self) -> float:
+        return self.attn_scale or self.resolved_head_dim ** -0.5
+
+    @property
+    def attn_in_dim(self) -> int:
+        """Width of the attention's input: zamba2's shared block reads the
+        stream concatenated with the token embedding."""
+        return 2 * self.d_model if self.concat_embed else self.d_model
+
+    @property
+    def ssm_conv_channels(self) -> int:
+        return self.ssm_d_inner + 2 * self.ssm_ngroups * self.ssm_state
 
     @property
     def ssm_d_inner(self) -> int:
@@ -119,27 +141,22 @@ class ArchConfig:
             di = 2 * d
             per_layer = 4 * d * di + 3 * di + 2 * d
         elif self.family == "hybrid":
-            di = self.ssm_d_inner
-            nh = self.ssm_n_heads
-            mamba = (
-                d * (2 * di + 2 * self.ssm_state * 0 + nh)  # in_proj(x,z)+dt
-                + di * (2 * self.ssm_state)                  # B,C proj (grouped)
-                + di * d                                      # out_proj
-                + self.ssm_conv * di
-                + 2 * nh
-            )
-            per_layer = mamba + 2 * d
-            shared = self._attn_params() + 3 * d * self.d_ff + 2 * d
-            n_shared_applications = (
-                self.n_layers // self.attn_every if self.attn_every else 0
-            )
-            # shared block: counted once (weights shared), plus per-layer mamba
-            return emb + self.n_layers * per_layer + shared + d
+            di, nh, cc = self.ssm_d_inner, self.ssm_n_heads, self.ssm_conv_channels
+            mamba = (d * (di + cc + nh)           # in_proj: z, x/B/C, dt
+                     + (self.ssm_conv + 1) * cc    # depthwise conv and bias
+                     + 3 * nh + di                 # dt_bias, A_log, D; gated norm
+                     + di * d + d)                 # out_proj; layer norm
+            din = self.attn_in_dim
+            shared = (self._attn_params(din) + 3 * d * self.d_ff + din + d)
+            use = d * d + (self.adapter_rank * (d + 2 * self.d_ff)
+                           if self.adapter_rank else 0)
+            return (emb + self.n_layers * mamba + self.num_mem_blocks * shared
+                    + len(self.hybrid_layer_ids) * use + d)
         total = emb + self.n_layers * per_layer + d
         return total
 
-    def _attn_params(self) -> int:
-        d, hd = self.d_model, self.resolved_head_dim
+    def _attn_params(self, d_in: int = 0) -> int:
+        d, hd = d_in or self.d_model, self.resolved_head_dim
         if self.attn_type == "mla":
             r = self.kv_lora_rank
             qd = self.qk_rope_head_dim + self.qk_nope_head_dim
@@ -155,7 +172,7 @@ class ArchConfig:
             return q + kv + o
         q = d * self.n_heads * hd
         kv = 2 * d * self.n_kv_heads * hd
-        o = self.n_heads * hd * d
+        o = self.n_heads * hd * self.d_model
         b = (self.n_heads + 2 * self.n_kv_heads) * hd if self.qkv_bias else 0
         return q + kv + o + b
 
@@ -184,7 +201,7 @@ class ArchConfig:
         """Tiny same-family config for CPU smoke tests."""
         kw = dict(
             name=self.name + "-smoke",
-            n_layers=min(self.n_layers, 2 if self.attn_every == 0 else 4),
+            n_layers=min(self.n_layers, 7 if self.hybrid_layer_ids else 2),
             d_model=128,
             n_heads=4,
             n_kv_heads=min(self.n_kv_heads, 2)
@@ -211,9 +228,12 @@ class ArchConfig:
                 dense_d_ff=256 if self.dense_d_ff else 0,
             )
         if self.family in ("ssm", "hybrid"):
-            kw.update(ssm_state=16, ssm_head_dim=16)
-        if self.attn_every:
-            kw.update(attn_every=2)
+            kw.update(ssm_state=16, ssm_head_dim=16,
+                      ssm_ngroups=min(self.ssm_ngroups, 2))
+        if self.hybrid_layer_ids:
+            # uneven stretches between uses, two of them adjacent
+            kw.update(hybrid_layer_ids=(1, 4, 5),
+                      adapter_rank=min(self.adapter_rank, 8))
         if self.sliding_window:
             kw.update(sliding_window=64)
         return replace(self, **kw)
@@ -257,4 +277,5 @@ def _ensure_loaded() -> None:
         stablelm_3b,
         xlstm_350m,
         zamba2_1p2b,
+        zamba2_7b,
     )

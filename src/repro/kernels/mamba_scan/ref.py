@@ -99,14 +99,23 @@ def ssd_chunk_ref(xd, da, Bm, Cm, *, chunk=128, initial_state=None):
 
 
 # ----------------------------------------------------- mamba2 conveniences
-def _mamba_args(x, dt, A, Bm, Cm):
+def per_head(t, H):
+    """B or C (B,T,G,N) repeated to (B,T,H,N): head h reads group h // (H/G)."""
+    G = t.shape[2]
+    return t if G == H else jnp.repeat(t, H // G, axis=2)
+
+
+def _mamba_args(x, dt, A, Bm, Cm, per_heads=True):
+    """(xd, da, B, C) of the general recurrence; Bm/Cm (B,T,N) (one group)
+    or (B,T,G,N), repeated per head unless ``per_heads`` is False."""
     H = x.shape[2]
     f32 = jnp.float32
     xd = x.astype(f32) * dt.astype(f32)[..., None]
     da = dt.astype(f32) * A.astype(f32)[None, None, :]
-    Bh = jnp.broadcast_to(Bm[:, :, None, :], (*dt.shape, Bm.shape[-1]))
-    Ch = jnp.broadcast_to(Cm[:, :, None, :], (*dt.shape, Cm.shape[-1]))
-    return xd, da, Bh.astype(f32), Ch.astype(f32)
+    Bg, Cg = (t[:, :, None, :] if t.ndim == 3 else t for t in (Bm, Cm))
+    if per_heads:
+        Bg, Cg = per_head(Bg, H), per_head(Cg, H)
+    return xd, da, Bg.astype(f32), Cg.astype(f32)
 
 
 def mamba_scan_ref(x, dt, A, Bm, Cm, *, initial_state=None):

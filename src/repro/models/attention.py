@@ -72,13 +72,13 @@ def _layer(buf, layer):
 
 # =========================================================== GQA attention
 def gqa_specs(cfg: ArchConfig) -> dict:
-    d, hd = cfg.d_model, cfg.resolved_head_dim
+    d, hd = cfg.attn_in_dim, cfg.resolved_head_dim
     hq, hkv = cfg.n_heads, cfg.n_kv_heads
     s = {
         "wq": Spec((d, hq * hd), ("embed", "heads")),
         "wk": Spec((d, hkv * hd), ("embed", "kv_heads")),
         "wv": Spec((d, hkv * hd), ("embed", "kv_heads")),
-        "wo": Spec((hq * hd, d), ("heads", "embed"), scale=0.5),
+        "wo": Spec((hq * hd, cfg.d_model), ("heads", "embed"), scale=0.5),
     }
     if cfg.qkv_bias:
         s["bq"] = Spec((hq * hd,), ("heads",), init="zeros")
@@ -123,7 +123,7 @@ def gqa_apply(
     written into its row of the stacked buffer and the attention reads
     that row; the buffer comes back whole, updated in place."""
     B, T, D = x.shape
-    hd = cfg.resolved_head_dim
+    hd, scale = cfg.resolved_head_dim, cfg.resolved_attn_scale
     hq, hkv = cfg.n_heads, cfg.n_kv_heads
 
     q = x @ p["wq"]
@@ -148,7 +148,7 @@ def gqa_apply(
         if global_layer is not None:
             window = jnp.where(global_layer, 0, window)
         out, kc, vc = _decode_heads(
-            (q * hd ** -0.5).reshape(B, hq * hd), k.reshape(B, hkv * hd),
+            (q * scale).reshape(B, hq * hd), k.reshape(B, hkv * hd),
             v.reshape(B, hkv * hd), cache, layer, cache_len, window, mesh)
         new_cache = KVCache(pin_cache(kc, mesh), pin_cache(vc, mesh))
     else:
@@ -176,6 +176,7 @@ def gqa_apply(
                 mesh,
                 causal=True,
                 window=w,
+                scale=scale,
             ).transpose(0, 2, 1, 3)
 
         if global_layer is None:

@@ -21,12 +21,7 @@ from repro.models.attention import (
 from repro.models.common import mlp_apply, mlp_specs, rms_norm, rms_norm_spec
 from repro.models.moe import moe_capacity_apply, moe_ep_apply, moe_specs
 from repro.models.spec import Spec
-from repro.models.ssm import (
-    MambaCache,
-    init_mamba_cache,
-    mamba_apply,
-    mamba_specs,
-)
+from repro.models.ssm import mamba_apply, mamba_specs
 from repro.models.xlstm import (
     MLSTMCache,
     SLSTMCache,
@@ -112,52 +107,77 @@ def attn_block_apply(
 
 # ======================================================== zamba2 hybrid
 def zamba_layer_specs(cfg: ArchConfig) -> dict:
+    """One Mamba2 layer: RMSNorm, mixer, residual."""
     return {"mamba": mamba_specs(cfg), "norm": rms_norm_spec(cfg.d_model)}
 
 
 def zamba_shared_specs(cfg: ArchConfig) -> dict:
-    """Single weight-tied transformer block applied every ``attn_every``."""
-    return attn_block_specs(cfg, cfg.d_ff, moe=False)
+    """One shared transformer block (zamba2's ``Zamba2AttentionDecoderLayer``):
+    RMSNorm of its input ([stream ‖ embedding] with ``concat_embed``),
+    attention, RMSNorm, gated MLP.  Its uses share these weights."""
+    return {
+        "input_norm": Spec((cfg.attn_in_dim,), ("embed",), init="ones"),
+        "attn": gqa_specs(cfg),
+        "ff_norm": rms_norm_spec(cfg.d_model),
+        "mlp": mlp_specs(cfg.d_model, cfg.d_ff),
+    }
 
 
-def zamba_layer_apply(
-    p, shared_p, x, cfg: ArchConfig, positions, layer_idx,
-    cache: Optional[dict] = None, cache_len=None, mesh=None,
-):
-    """One mamba layer; on every ``attn_every``-th layer also the shared
-    attention block (weight-tied across applications)."""
-    h = rms_norm(p["norm"], x, cfg.norm_eps)
-    m_cache = cache["mamba"] if cache is not None else None
-    y, new_m_cache = mamba_apply(p["mamba"], h, cfg, cache=m_cache, mesh=mesh)
-    x = x + y
+def zamba_use_specs(cfg: ArchConfig) -> dict:
+    """What each use of a shared block has of its own: the LoRA on the
+    MLP's gate and up projections, and the ``linear`` that maps the
+    block's output onto the next Mamba2 layer's input."""
+    d = cfg.d_model
+    s = {"linear": Spec((d, d), ("embed", None))}
+    if cfg.adapter_rank:
+        s["adapter"] = {
+            "down": Spec((d, cfg.adapter_rank), ("embed", None)),
+            "up": Spec((cfg.adapter_rank, 2 * cfg.d_ff), (None, "mlp")),
+        }
+    return s
 
-    apply_shared = (layer_idx % cfg.attn_every) == cfg.attn_every - 1
 
-    if cache is None:
-        def with_shared_nc(x):
-            return attn_block_apply(shared_p, x, cfg, positions, moe=False,
-                                    mesh=mesh)[0]
+def zamba_layer_apply(p, x, add, cfg: ArchConfig, cache=None, layer=None, mesh=None):
+    """x + Mamba2(RMSNorm(x + add)): ``add`` is a shared block's output
+    through its use's ``linear`` (zeros where no block feeds this layer)."""
+    with jax.named_scope("mamba"):
+        y, cache = mamba_apply(p["mamba"], rms_norm(p["norm"], x + add, cfg.norm_eps), cfg,
+                               cache=cache, layer=layer, mesh=mesh)
+    return x + y, cache
 
-        x2 = jax.lax.cond(apply_shared, with_shared_nc, lambda x: x, x)
-        new_kv = None
-    else:
-        def with_shared(args):
-            x, kv = args
-            # this layer's own KV cache, as a stack of one
-            y, new_kv, _ = attn_block_apply(
-                shared_p, x, cfg, positions, moe=False,
-                cache=jax.tree.map(lambda a: a[None], kv),
-                cache_len=cache_len, layer=0, mesh=mesh,
-            )
-            return y, jax.tree.map(lambda a: a[0], new_kv)
 
-        x2, new_kv = jax.lax.cond(
-            apply_shared, with_shared, lambda a: a, (x, cache["kv"])
-        )
-    new_cache = (
-        {"mamba": new_m_cache, "kv": new_kv} if cache is not None else None
-    )
-    return x2, new_cache
+def zamba_shared_apply(p, use, x, emb, cfg: ArchConfig, positions, *, cache=None,
+                       cache_len=None, use_idx=None, mesh=None):
+    """One use of a shared block: its output through the use's ``linear``,
+    to be added to the next Mamba2 layer's input (the block has no
+    residual of its own).  ``cache`` is the KV stack of the uses, row
+    ``use_idx`` this use's."""
+    with jax.named_scope("shared_block"):
+        h = jnp.concatenate([x, emb], axis=-1) if cfg.concat_embed else x
+        h = rms_norm(p["input_norm"], h, cfg.norm_eps)
+        with jax.named_scope("attn"):
+            a, cache = gqa_apply(p["attn"], h, cfg, positions, cache=cache,
+                                 cache_len=cache_len, layer=use_idx, mesh=mesh)
+        h = rms_norm(p["ff_norm"], a, cfg.norm_eps)
+        with jax.named_scope("mlp"):
+            m = gated_mlp_apply(p["mlp"], h, use.get("adapter"))
+    with jax.named_scope("hybrid_linear"):
+        out = m @ use["linear"]
+    return out, cache
+
+
+def gated_mlp_apply(p, x, adapter):
+    """gelu(x·gate) · (x·up), then down (zamba2's ``hidden_act`` "gelu",
+    the exact form); ``adapter`` (a LoRA, or None) adds (x·A)·B to gate
+    and up, its first half to gate."""
+    g, u = x @ p["gate"], x @ p["up"]
+    if adapter is not None:
+        # one product a half: the LoRA's whole 2·d_ff output would be a
+        # temporary twice the MLP's width
+        xa, ff = x @ adapter["down"], g.shape[-1]
+        g = g + xa @ adapter["up"][:, :ff]
+        u = u + xa @ adapter["up"][:, ff:]
+    return (jax.nn.gelu(g, approximate=False) * u) @ p["down"]
 
 
 # ========================================================== xLSTM groups

@@ -68,7 +68,11 @@ class Model:
             s["layers"] = stack_specs(
                 B.zamba_layer_specs(cfg), cfg.n_layers
             )
-            s["shared"] = B.zamba_shared_specs(cfg)
+            # the shared blocks and the uses' own weights as lists, not
+            # stacks: a use reads its block whole, never a slice of a stack
+            s["shared"] = [B.zamba_shared_specs(cfg)
+                           for _ in range(cfg.num_mem_blocks)]
+            s["hybrid"] = [B.zamba_use_specs(cfg) for _ in cfg.hybrid_layer_ids]
         elif fam == "ssm":
             n_groups = cfg.n_layers // cfg.slstm_every
             s["layers"] = stack_specs(
@@ -119,7 +123,7 @@ class Model:
                 else {"dense": cache_d, "main": cache_m}
             )
         elif fam == "hybrid":
-            x, new_cache = self._scan_zamba(
+            x, new_cache = self._run_zamba(
                 params, x, positions, cache, cache_len
             )
         else:  # ssm / xlstm
@@ -172,27 +176,54 @@ class Model:
             )
         return x, pin(new_cache), auxs.sum()
 
-    def _scan_zamba(self, params, x, positions, cache, cache_len):
+    def _run_zamba(self, params, x, positions, cache, cache_len):
+        """zamba2's layers in their published order.  The Mamba2 layers run
+        as `lax.scan`s, one over each stretch between two hybrid layers;
+        before each hybrid layer, in Python, a shared block (the uses take
+        the ``num_mem_blocks`` blocks in turn) reads [stream ‖ embedding]
+        and its use's ``linear`` output is added to that layer's input.
+        The stacked Mamba2 cache rides in each scan's carry and the KV
+        stack of the uses beside it: every layer writes its own row, so
+        both are updated in place."""
         cfg = self.cfg
-        shared = params["shared"]
+        emb = x
+        mamba = None if cache is None else cache["mamba"]
+        kv = None if cache is None else cache["kv"]
+        stack = params["layers"]
 
+        # one body for every stretch, so that it is traced and lowered once
         @jax.named_scope("block")
-        def body(carry, inp):
-            x, i = carry
-            p, c = inp
-            y, new_c = B.zamba_layer_apply(
-                p, shared, x, cfg, positions, i, cache=c, cache_len=cache_len,
-                mesh=self.mesh,
-            )
-            return (y, i + 1), new_c
+        def body(carry, _):
+            x, add, i, c = carry
+            p = jax.tree.map(lambda a: jax.lax.dynamic_index_in_dim(
+                a, i, keepdims=False), stack)
+            y, c = B.zamba_layer_apply(p, x, add, cfg, cache=c, layer=i,
+                                       mesh=self.mesh)
+            return (y, jnp.zeros_like(add), i + 1, c), None
 
         if self.remat == "full":
             body = jax.checkpoint(body)
+
+        def run(x, add, start, stop, mamba):
+            (x, _, _, mamba), _ = jax.lax.scan(
+                body, (x, add, jnp.asarray(start, jnp.int32), mamba), None,
+                length=stop - start)
+            return x, mamba
+
+        # the uses as calls of one jitted function, traced and lowered once
+        # (its kernels too); XLA inlines the calls
+        shared = jax.jit(functools.partial(B.zamba_shared_apply, cfg=cfg, mesh=self.mesh))
+        bounds = [*cfg.hybrid_layer_ids, cfg.n_layers]
         with jax.named_scope("layers"):
-            (x, _), new_cache = jax.lax.scan(
-                body, (x, 0), (params["layers"], cache)
-            )
-        return x, new_cache
+            x, mamba = run(x, jnp.zeros_like(x), 0, bounds[0], mamba)
+            for k, (start, stop) in enumerate(zip(bounds, bounds[1:])):
+                with jax.named_scope("block"):
+                    add, kv = shared(
+                        params["shared"][k % cfg.num_mem_blocks],
+                        params["hybrid"][k], x, emb, positions=positions, cache=kv,
+                        cache_len=cache_len, use_idx=jnp.asarray(k, jnp.int32))
+                x, mamba = run(x, add, start, stop, mamba)
+        return x, None if cache is None else {"mamba": mamba, "kv": kv}
 
     def _scan_xlstm(self, params, x, cache):
         cfg = self.cfg
@@ -268,17 +299,10 @@ class Model:
                 "main": kv(cfg.n_layers - cfg.first_dense_layers),
             }
         if fam == "hybrid":
-            L = cfg.n_layers
-
-            def stack(tree, n):
-                return jax.tree.map(
-                    lambda a: jnp.broadcast_to(a[None], (n, *a.shape)).copy(),
-                    tree,
-                )
-
+            # Mamba2 state for every layer; K/V only for the shared blocks' uses
             return {
-                "mamba": stack(init_mamba_cache(cfg, batch, dtype), L),
-                "kv": stack(init_kv_cache(cfg, batch, s_max, dtype), L),
+                "mamba": init_mamba_cache(cfg, batch, dtype, cfg.n_layers),
+                "kv": kv(len(cfg.hybrid_layer_ids)),
             }
         if fam == "ssm":
             n_groups = cfg.n_layers // cfg.slstm_every
@@ -365,9 +389,9 @@ class Model:
 
             def mamba_one(x):
                 sh = x.shape
-                if len(sh) == 5:   # state (L,B,H,N,P)
+                if len(sh) == 5:   # state (L,B,rows,N,lanes), mamba_scan.layout
                     return P(None, dp_for(sh[1]), m_for(sh[2]), None, None)
-                return P(None, dp_for(sh[1]), None, m_for(sh[3]))  # conv
+                return P(None, None, dp_for(sh[2]), m_for(sh[3]))  # conv (L,k-1,B,C)
             return {"mamba": jax.tree.map(mamba_one, c["mamba"]),
                     "kv": kv_spec(c["kv"], (None,))}
         # ssm / xlstm
@@ -384,6 +408,18 @@ class Model:
 
         return {"mlstm": jax.tree.map(ml_one, c["mlstm"]),
                 "slstm": jax.tree.map(sl_one, c["slstm"])}
+
+    def cache_nbytes(self, cache) -> Dict[str, int]:
+        """Bytes of each kind of state in ``cache`` (an `init_cache` tree),
+        read from its arrays: ``kv`` (attention K/V or latents) and ``ssm``
+        (recurrent state and conv inputs)."""
+        def nbytes(tree):
+            return sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(tree))
+
+        fam = self.cfg.family
+        if fam == "hybrid":
+            return {"ssm": nbytes(cache["mamba"]), "kv": nbytes(cache["kv"])}
+        return {"ssm" if fam == "ssm" else "kv": nbytes(cache)}
 
     # dense/moe caches are dicts keyed like the scan stacks already
     def _wrap_cache(self, cache):

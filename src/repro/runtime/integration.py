@@ -55,6 +55,8 @@ def decode_step_descs(cfg, batch: int, dtype: str = "bf16") -> List[Tuple[str, L
     hd = cfg.resolved_head_dim
     out: List[Tuple[str, List[GemmDesc]]] = []
 
+    if cfg.family == "hybrid":
+        return _hybrid_descs(cfg, M, dtype)
     if cfg.attn_type == "mla":
         # MLA (DeepSeek-V2): low-rank KV/Q down-projections + up-projection.
         q_n = cfg.n_heads * (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim)
@@ -68,7 +70,7 @@ def decode_step_descs(cfg, batch: int, dtype: str = "bf16") -> List[Tuple[str, L
                                      GemmDesc(M, kv_n, D, dtype=dtype)]))
         out.append(("attn-out", [GemmDesc(M, D, cfg.n_heads * cfg.v_head_dim,
                                           dtype=dtype)]))
-    elif cfg.family in ("ssm",) or (cfg.family == "hybrid" and cfg.ssm_state):
+    elif cfg.family == "ssm":
         # Mamba2-style block: wide in-projection + out-projection.
         out.append(("ssm-in", [GemmDesc(M, 2 * cfg.ssm_d_inner, D, dtype=dtype)]))
         out.append(("ssm-out", [GemmDesc(M, D, cfg.ssm_d_inner, dtype=dtype)]))
@@ -100,6 +102,29 @@ def decode_step_descs(cfg, batch: int, dtype: str = "bf16") -> List[Tuple[str, L
                                GemmDesc(M, ff, D, dtype=dtype)]))
         out.append(("ffn-down", [GemmDesc(M, D, ff, dtype=dtype)]))
     return out
+
+
+def _hybrid_descs(cfg, M: int, dtype: str) -> List[Tuple[str, List[GemmDesc]]]:
+    """One zamba2 hybrid layer in its published series order and widths:
+    the shared block's q/k/v over its input ([stream ‖ embedding] with
+    ``concat_embed``) and its output projection, the gated MLP with the
+    use's LoRA (down to the rank, up to gate and up), the use's
+    ``linear``, then the Mamba2 layer's in- and out-projections."""
+    D, hd, ff = cfg.d_model, cfg.resolved_head_dim, cfg.d_ff
+    din = cfg.attn_in_dim
+    di, H = cfg.ssm_d_inner, cfg.ssm_n_heads
+    out = [("qkv", [GemmDesc(M, cfg.n_heads * hd, din, dtype=dtype),
+                    GemmDesc(M, cfg.n_kv_heads * hd, din, dtype=dtype),
+                    GemmDesc(M, cfg.n_kv_heads * hd, din, dtype=dtype)]),
+           ("attn-out", [GemmDesc(M, D, cfg.n_heads * hd, dtype=dtype)]),
+           ("ffn-up", [GemmDesc(M, ff, D, dtype=dtype), GemmDesc(M, ff, D, dtype=dtype)])]
+    if cfg.adapter_rank:
+        out += [("adapter-down", [GemmDesc(M, cfg.adapter_rank, D, dtype=dtype)]),
+                ("adapter-up", [GemmDesc(M, 2 * ff, cfg.adapter_rank, dtype=dtype)])]
+    return out + [("ffn-down", [GemmDesc(M, D, ff, dtype=dtype)]),
+                  ("linear", [GemmDesc(M, D, D, dtype=dtype)]),
+                  ("ssm-in", [GemmDesc(M, di + cfg.ssm_conv_channels + H, D, dtype=dtype)]),
+                  ("ssm-out", [GemmDesc(M, D, di, dtype=dtype)])]
 
 
 def decode_step_requests(
@@ -225,8 +250,11 @@ def decode_step_graph(
     - MoE: the routed pool as its two ragged grouped-GEMM launches
       (routing scatter = control edge in, up→down = data edge) plus the
       shared-expert dense MLP, all fed by the attention output;
-    - SSM/hybrid: in-projection → SSD scan → out-projection, with the
-      attention branch (hybrid) running in parallel off the layer input.
+    - SSM: in-projection → SSD scan → out-projection;
+    - hybrid (zamba2), in series as the published layer runs: the shared
+      block's q/k/v → attention → O-projection → gate/up and the LoRA →
+      down → the use's linear → the Mamba2 in-projection → SSD scan →
+      out-projection.
 
     Consecutive layers chain by control edges from layer sinks to the
     next layer's input projections.  Per-layer node names are prefixed
@@ -255,6 +283,8 @@ def _add_decode_layer(
     control-edge sources)."""
     bundles = dict(decode_step_descs(cfg, batch, dtype))
     P = prefix
+    if cfg.family == "hybrid":
+        return _add_hybrid_layer(g, cfg, bundles, batch, context, dtype, P, roots_after)
     sinks: List[str] = []
 
     # ------------------------------------------------ attention / SSM
@@ -296,15 +326,6 @@ def _add_decode_layer(
                          feeds={0: ssm_in}, tag="scan")
             block_out = _wire(g, P + "ssm-out", bundles["ssm-out"][0],
                               feeds={"a": scan}, tag="ssm-out")
-        if cfg.family == "hybrid":
-            # Hybrid (Zamba-style): the shared attention block runs off
-            # the same layer input, in parallel with the Mamba branch.
-            hd = cfg.resolved_head_dim
-            sinks.append(_wire(
-                g, P + "attn",
-                AttentionDesc(batch, cfg.n_heads, cfg.n_kv_heads, 1,
-                              context, hd, True, dtype),
-                after=roots_after, tag="attn"))
     else:
         qkv = bundles["qkv"]
         hd = cfg.resolved_head_dim
@@ -353,6 +374,35 @@ def _add_decode_layer(
     else:
         sinks.append(block_out)
     return sinks
+
+
+def _add_hybrid_layer(g: OpGraph, cfg, b, batch: int, context: int, dtype: str,
+                      P: str, roots_after: List[str]) -> List[str]:
+    """Wire one zamba2 hybrid layer in series (`_hybrid_descs`' order)."""
+    q = _wire(g, P + "q", b["qkv"][0], after=roots_after, tag="qkv")
+    k = _wire(g, P + "k", b["qkv"][1], after=roots_after, tag="qkv")
+    v = _wire(g, P + "v", b["qkv"][2], after=roots_after, tag="qkv")
+    attn = _wire(g, P + "attn",
+                 AttentionDesc(batch, cfg.n_heads, cfg.n_kv_heads, 1, context,
+                               cfg.resolved_head_dim, True, dtype),
+                 feeds={0: q}, after=[k, v], tag="attn")
+    o = _wire(g, P + "o", b["attn-out"][0], feeds={"a": attn}, tag="attn-out")
+    gate = _wire(g, P + "gate", b["ffn-up"][0], feeds={"a": o}, tag="ffn-up")
+    up = _wire(g, P + "up", b["ffn-up"][1], feeds={"a": o}, tag="ffn-up")
+    before_down = [gate]
+    if "adapter-down" in b:
+        lora = _wire(g, P + "adapter-down", b["adapter-down"][0], feeds={"a": o},
+                     tag="adapter-down")
+        before_down.append(_wire(g, P + "adapter-up", b["adapter-up"][0],
+                                 feeds={"a": lora}, tag="adapter-up"))
+    down = _wire(g, P + "down", b["ffn-down"][0], feeds={"a": up}, after=before_down,
+                 tag="ffn-down")
+    linear = _wire(g, P + "linear", b["linear"][0], feeds={"a": down}, tag="linear")
+    ssm_in = _wire(g, P + "ssm-in", b["ssm-in"][0], feeds={"a": linear}, tag="ssm-in")
+    scan = _wire(g, P + "scan",
+                 ScanDesc(batch, 1, cfg.ssm_n_heads, cfg.ssm_head_dim, cfg.ssm_state, dtype),
+                 feeds={0: ssm_in}, tag="scan")
+    return [_wire(g, P + "ssm-out", b["ssm-out"][0], feeds={"a": scan}, tag="ssm-out")]
 
 
 def submit_decode_graph(

@@ -32,13 +32,15 @@ from repro.models.model import Model
 class Decoded(NamedTuple):
     """What `greedy_decode` served, with its times split by phase: the
     compile of the prefill and decode programs, the prefill itself, and
-    the decode loop (every step, runtime dispatch included)."""
+    the decode loop (every step, runtime dispatch included); and the
+    bytes of each kind of cache it served from (`Model.cache_nbytes`)."""
 
     tokens: jax.Array          # (B, steps)
     prefill_logits: jax.Array  # (B, 1, V) last-prompt-token logits
     compile_s: float
     prefill_s: float
     decode_s: float
+    cache_bytes: dict          # {"kv": ...} and, for hybrids, {"ssm": ...}
 
 
 def make_serve_fns(model: Model) -> Tuple[Callable, Callable]:
@@ -82,6 +84,7 @@ def greedy_decode(
     ready — concurrent requests overlap across stage boundaries."""
     B = jax.tree.leaves(prompt_batch)[0].shape[0]
     cache = model.init_cache(batch=B, s_max=s_max, dtype=cache_dtype)
+    cache_bytes = model.cache_nbytes(cache)
     t0 = time.perf_counter()
     with TraceAnnotation("serve.compile", batch=B):
         prefill = serving_jit(model.prefill).lower(
@@ -148,4 +151,4 @@ def greedy_decode(
                         runtime.flush(force=True)
     tokens = jax.block_until_ready(jnp.concatenate(out, axis=1))
     return Decoded(tokens, prefill_logits, compile_s, prefill_s,
-                   time.perf_counter() - t0)
+                   time.perf_counter() - t0, cache_bytes)

@@ -112,8 +112,8 @@ def test_ragged_gemm_compiles(one_chip):
              one_chip, ((64, 2560), BF16), ((3, 2560, 6912), BF16))
 
 
-@pytest.mark.parametrize("hd,heads,kv_heads", [(80, 32, 32), (128, 40, 8)],
-                         ids=["stablelm-hd80", "qwen3-hd128"])
+@pytest.mark.parametrize("hd,heads,kv_heads", [(80, 32, 32), (128, 40, 8), (224, 32, 32)],
+                         ids=["stablelm-hd80", "qwen3-hd128", "zamba2-7b-hd224"])
 def test_flash_prefill_compiles(one_chip, hd, heads, kv_heads):
     T, S = 128, 256
     _compile(lambda q, k, v: flash_attention(q, k, v, causal=True,
@@ -136,13 +136,14 @@ def test_flash_decode_compiles(one_chip):
 # (GQA 5:1, hd 128), cache capacity 769 rounded up to 896; and at the long
 # capacities the repo declares, where the positions take several grid
 # steps: stablelm-3b at 8k, qwen3-14b at decode_32k, and zamba2-1.2b's
-# shared attention (hd 64) at long_500k.
+# shared attention (hd 64) at long_500k; and zamba2-7b's shared blocks (MHA
+# of 32 heads of 224, the KV stack of its 4 uses).
 @pytest.mark.parametrize("L,B,hq,hkv,hd,S", [
     (32, 16, 32, 32, 80, 896), (40, 32, 10, 2, 128, 896), (8, 4, 16, 8, 128, 896),
     (2, 16, 32, 32, 80, 8192), (1, 8, 40, 8, 128, 32768),
-    (1, 1, 32, 32, 64, 524288)],
+    (1, 1, 32, 32, 64, 524288), (4, 32, 32, 32, 224, 896)],
     ids=["stablelm-hd80", "qwen3-tp4-hd128", "gqa-2to1", "stablelm-8k",
-         "qwen3-32k", "zamba2-512k"])
+         "qwen3-32k", "zamba2-512k", "zamba2-7b-hd224"])
 def test_decode_attention_compiles(one_chip, L, B, hq, hkv, hd, S):
     cache = ((L, B, hkv, hd, S), BF16)
     new = ((B, hkv * hd), BF16)
@@ -189,6 +190,48 @@ def test_decode_step_updates_the_cache_in_place(one_chip):
     assert mem.alias_size_in_bytes == cache_bytes
     assert mem.temp_size_in_bytes < layer_bytes // 8
     assert "goldyloc_decode_attn" in compiled.as_text()
+
+
+def test_zamba2_decode_step_updates_its_caches_in_place(one_chip):
+    """zamba2-7b's decode step at its widths (12 layers, the uses at 6 and
+    11, one of each shared block), compiled as `greedy_decode` compiles
+    it: the Mamba2 state and conv stacks and the uses' KV stack are
+    donated and aliased to the output, none padded (the state's 64-wide
+    heads sit two a lane row), and the program holds no copy of any of
+    them: no layer scan relays a stack out between the programs."""
+    import dataclasses
+
+    from jax.sharding import Mesh, NamedSharding
+
+    from repro.configs import get_arch
+    from repro.dist.sharding import named, params_pspecs
+    from repro.models import build_model
+    from repro.train.serve_loop import serving_jit
+
+    mesh = Mesh(np.asarray(list(one_chip.device_set)).reshape(1, 1), ("data", "model"))
+    cfg = dataclasses.replace(get_arch("zamba2-7b"), n_layers=12, hybrid_layer_ids=(6, 11))
+    model = build_model(cfg, mesh=mesh)
+
+    def sds(tree, shardings):
+        return jax.tree.map(lambda a, sh: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=sh), tree, shardings)
+
+    params = jax.eval_shape(lambda k: model.init(k, BF16), jax.random.PRNGKey(0))
+    params = sds(params, named(mesh, params_pspecs(model, mesh)))
+    cache = jax.eval_shape(lambda: model.init_cache(batch=32, s_max=769, dtype=BF16))
+    cache = sds(cache, named(mesh, model.cache_pspecs(mesh, cache)))
+    rep = NamedSharding(mesh, jax.sharding.PartitionSpec())
+    with force_pallas(True):
+        compiled = serving_jit(model.decode_step).lower(
+            params, jax.ShapeDtypeStruct((32, 1), jnp.int32, sharding=rep), cache,
+            jax.ShapeDtypeStruct((), jnp.int32, sharding=rep)).compile()
+    mem = compiled.memory_analysis()
+    cache_bytes = sum(model.cache_nbytes(cache).values())
+    state = cache["mamba"].state
+    state_layer = state.size * state.dtype.itemsize // cfg.n_layers
+    assert mem.alias_size_in_bytes == cache_bytes
+    assert mem.temp_size_in_bytes < state_layer // 4
+    assert compiled.as_text().count("goldyloc_decode_attn") >= 2
 
 
 @pytest.mark.parametrize("chunk", [t.bm for t in SCAN_TILES])

@@ -65,8 +65,8 @@ def test_forward_and_grad_step(arch):
 
 
 @pytest.mark.parametrize(
-    "arch", ["qwen3-14b", "deepseek-v2-lite-16b", "zamba2-1.2b", "xlstm-350m",
-             "stablelm-3b", "gemma3-27b"]
+    "arch", ["qwen3-14b", "deepseek-v2-lite-16b", "zamba2-1.2b", "zamba2-7b",
+             "xlstm-350m", "stablelm-3b", "gemma3-27b"]
 )
 def test_prefill_decode_consistency(arch):
     """Prefill+decode logits must match full-sequence forward (teacher
